@@ -28,6 +28,8 @@ def main() -> None:
     ap.add_argument("--names", default="wikipedia,jobs,opera,britannica")
     ap.add_argument("--json-out", default="results/bench")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     scale = args.scale or (1.0 if args.full else 0.25)
     names = args.names.split(",") if args.names != "all" else None
     os.makedirs(args.json_out, exist_ok=True)

@@ -601,6 +601,8 @@ def main():
                     help="seconds-scale CI tripwire: tiny graph, few "
                          "queries, perf gates skipped")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         args.n_nodes = min(args.n_nodes, 400)
         args.n_edges = min(args.n_edges, 3200)

@@ -44,15 +44,15 @@ def resolve_interpret(interpret=None) -> bool:
 def _bsr_kernel(idx_ref, block_ref, x_ref, cin_ref, y_ref, *, accum_dtype):
     """One nonzero block per grid step.
 
-    idx_ref: (nblocks, 2) scalar-prefetched (brow, bcol).
+    idx_ref: (2 * nblocks,) scalar-prefetched flat (brow, bcol) pairs.
     block_ref: (1, bs, bs) VMEM tile of A.
     x_ref:   (bs, V) VMEM tile of x rows for this block's columns.
     cin_ref: (bs, 1) VMEM tile of the scaling diagonal (same rows as x).
     y_ref:   (bs, V) VMEM output tile for this block's rows (revisited).
     """
     k = pl.program_id(0)
-    brow_k = idx_ref[k, 0]
-    brow_prev = idx_ref[jnp.maximum(k - 1, 0), 0]
+    brow_k = idx_ref[2 * k]
+    brow_prev = idx_ref[2 * jnp.maximum(k - 1, 0)]
     is_first = jnp.logical_or(k == 0, brow_k != brow_prev)
 
     @pl.when(is_first)
@@ -61,8 +61,13 @@ def _bsr_kernel(idx_ref, block_ref, x_ref, cin_ref, y_ref, *, accum_dtype):
 
     xs = (x_ref[...] * cin_ref[...]).astype(accum_dtype)
     blk = block_ref[0].astype(accum_dtype)
-    y_ref[...] += jnp.dot(blk, xs, preferred_element_type=accum_dtype
-                          ).astype(y_ref.dtype)
+    # Mosaic's default contracts f32 operands in one bf16 pass (~1e-3
+    # relative); f32 blocks ask for f32. bf16 blocks lose nothing at the
+    # default, which keeps the ladder's bulk sweeps single-pass.
+    precision = (None if block_ref.dtype == jnp.bfloat16
+                 else jax.lax.Precision.HIGHEST)
+    y_ref[...] += jnp.dot(blk, xs, preferred_element_type=accum_dtype,
+                          precision=precision).astype(y_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bs", "interpret", "accum_dtype"))
@@ -73,22 +78,33 @@ def _bsr_scaled_matvec(blocks, idx, x, cin, *, bs: int, interpret: bool,
     v = x.shape[1]
     cv = cin.shape[1]
 
+    # block indices must be i32: a Python 0 traces as i64 under
+    # jax_enable_x64, and Mosaic refuses an index map returning i64. The
+    # zero is made inside each map (an index map may not capture one).
+    def block_k(k, idx_ref):
+        return k, jnp.int32(0), jnp.int32(0)
+
+    def row_of(col):  # 0: brow, 1: bcol
+        return lambda k, idx_ref: (idx_ref[2 * k + col], jnp.int32(0))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nblocks,),
         in_specs=[
-            pl.BlockSpec((1, bs, bs), lambda k, idx_ref: (k, 0, 0)),
-            pl.BlockSpec((bs, v), lambda k, idx_ref: (idx_ref[k, 1], 0)),
-            pl.BlockSpec((bs, cv), lambda k, idx_ref: (idx_ref[k, 1], 0)),
+            pl.BlockSpec((1, bs, bs), block_k),
+            pl.BlockSpec((bs, v), row_of(1)),
+            pl.BlockSpec((bs, cv), row_of(1)),
         ],
-        out_specs=pl.BlockSpec((bs, v), lambda k, idx_ref: (idx_ref[k, 0], 0)),
+        out_specs=pl.BlockSpec((bs, v), row_of(0)),
     )
+    # the table goes to SMEM flat: a 2-D (nblocks, 2) table pads its minor
+    # dim to 128 lanes there, which caps it near 2k blocks
     return pl.pallas_call(
         functools.partial(_bsr_kernel, accum_dtype=accum_dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_pad, v), x.dtype),
         interpret=interpret,
-    )(idx, blocks, x, cin)
+    )(idx.reshape(-1), blocks, x, cin)
 
 
 def bsr_scaled_matvec(blocks, idx, x, cin, *, bs: int,

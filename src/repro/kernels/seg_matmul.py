@@ -39,8 +39,11 @@ def _seg_kernel(blkid_ref, msgs_ref, off_ref, valid_ref, y_ref, *, bs,
     rows = jax.lax.broadcasted_iota(jnp.int32, (bs, off.shape[0]), 0)
     onehot = (rows == off[:, 0][None, :]).astype(accum_dtype)  # (bs, tile_e)
     onehot = onehot * valid[:, 0][None, :]
-    y_ref[...] += jnp.dot(onehot, msgs, preferred_element_type=accum_dtype
-                          ).astype(y_ref.dtype)
+    # f32 messages contract in f32 (Mosaic's default is one bf16 pass)
+    precision = (None if msgs_ref.dtype == jnp.bfloat16
+                 else jax.lax.Precision.HIGHEST)
+    y_ref[...] += jnp.dot(onehot, msgs, preferred_element_type=accum_dtype,
+                          precision=precision).astype(y_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("n_blocks", "bs", "interpret",
@@ -58,15 +61,23 @@ def seg_matmul(blkid, msgs, off, valid, n_blocks: int, *, bs: int = 128,
     tile_e = msgs.shape[0] // n_tiles
     f = msgs.shape[1]
 
+    # i32 block indices, made inside the maps (see bsr_spmm: a Python 0
+    # is i64 under jax_enable_x64, which Mosaic refuses)
+    def tile_t(t, blkid_ref):
+        return t, jnp.int32(0)
+
+    def out_block(t, blkid_ref):
+        return blkid_ref[t], jnp.int32(0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_tiles,),
         in_specs=[
-            pl.BlockSpec((tile_e, f), lambda t, blkid_ref: (t, 0)),
-            pl.BlockSpec((tile_e, 1), lambda t, blkid_ref: (t, 0)),
-            pl.BlockSpec((tile_e, 1), lambda t, blkid_ref: (t, 0)),
+            pl.BlockSpec((tile_e, f), tile_t),
+            pl.BlockSpec((tile_e, 1), tile_t),
+            pl.BlockSpec((tile_e, 1), tile_t),
         ],
-        out_specs=pl.BlockSpec((bs, f), lambda t, blkid_ref: (blkid_ref[t], 0)),
+        out_specs=pl.BlockSpec((bs, f), out_block),
     )
     return pl.pallas_call(
         functools.partial(_seg_kernel, bs=bs, accum_dtype=accum_dtype),

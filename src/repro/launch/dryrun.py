@@ -19,13 +19,17 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
-from ..compat import set_mesh  # noqa: E402
 from ..configs import REGISTRY, get_spec  # noqa: E402
 from ..models.sharding import tree_filter_specs, filter_spec  # noqa: E402
 from ..sparse.dist import make_dryrun_rank_sweep  # noqa: E402
 from . import hlo_analysis  # noqa: E402
 from .mesh import make_production_mesh  # noqa: E402
 from .steps import build_step  # noqa: E402
+
+
+# the chip the production meshes are made of (the dry run compiles on host
+# devices, so the target is named, not read from jax.devices())
+TARGET_KIND = "TPU v5 lite"
 
 
 def _axis_size(a, mesh) -> int:
@@ -101,12 +105,13 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, out_dir: str,
             step = build_step(spec, shape_name, mode=mode)
             fn = step.fn
         in_sh = _to_named(step.in_specs, mesh, step.args)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(fn, in_shardings=in_sh)
             lowered = jitted.lower(*step.args)
             compiled = lowered.compile()
             analysis = hlo_analysis.analyze(
-                compiled, step.meta.get("model_flops_per_step", 0), n_devices)
+                compiled, step.meta.get("model_flops_per_step", 0), n_devices,
+                TARGET_KIND)
         result = {
             "arch": arch, "shape": shape_name, "mesh": mesh_name,
             "mode": mode, "status": "ok",

@@ -6,7 +6,9 @@ of every all-gather / all-reduce / reduce-scatter / all-to-all /
 collective-permute, with while-loop trip-count multipliers inferred from
 the loop condition (layer scans execute their collectives n_layers times).
 
-Hardware model (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Roofline terms are priced against ``PEAKS``, keyed by the chip's
+``Device.device_kind``; a kind missing from the table is an error, never a
+default.
 """
 from __future__ import annotations
 
@@ -14,9 +16,24 @@ import dataclasses
 import re
 from typing import Dict
 
-PEAK_FLOPS = 197e12      # bf16 per chip
-HBM_BW = 819e9           # bytes/s per chip
-ICI_BW = 50e9            # bytes/s per link
+# Published per-chip peaks keyed by jax ``Device.device_kind``: dense bf16
+# FLOP/s, HBM bytes/s, and bytes/s per ICI link. Source for "TPU v5 lite"
+# (v5e): Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB
+# HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip interconnect over 4 links.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """The published peaks of one chip of ``device_kind``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} (known: "
+            f"{sorted(PEAKS)}); add them to hlo_analysis.PEAKS with their "
+            f"source") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1, "f8e4m3": 1,
@@ -135,19 +152,23 @@ class Roofline:
     hbm_bytes_per_device: float
     collective_bytes_per_device: float
     n_devices: int
+    device_kind: str
     model_flops: float = 0.0
+
+    def __post_init__(self):
+        self.peaks = peaks(self.device_kind)
 
     @property
     def compute_s(self):
-        return self.flops_per_device / PEAK_FLOPS
+        return self.flops_per_device / self.peaks["flops"]
 
     @property
     def memory_s(self):
-        return self.hbm_bytes_per_device / HBM_BW
+        return self.hbm_bytes_per_device / self.peaks["hbm_bw"]
 
     @property
     def collective_s(self):
-        return self.collective_bytes_per_device / ICI_BW
+        return self.collective_bytes_per_device / self.peaks["ici_bw"]
 
     @property
     def bottleneck(self):
@@ -170,7 +191,8 @@ class Roofline:
         """MODEL_FLOPS-based MFU at the roofline step time: the score."""
         if self.step_time_s == 0:
             return 0.0
-        return (self.model_flops / self.n_devices / self.step_time_s) / PEAK_FLOPS
+        return (self.model_flops / self.n_devices / self.step_time_s
+                / self.peaks["flops"])
 
     def to_dict(self):
         return {
@@ -178,6 +200,7 @@ class Roofline:
             "hbm_bytes_per_device": self.hbm_bytes_per_device,
             "collective_bytes_per_device": self.collective_bytes_per_device,
             "n_devices": self.n_devices,
+            "device_kind": self.device_kind,
             "model_flops": self.model_flops,
             "compute_s": self.compute_s,
             "memory_s": self.memory_s,
@@ -189,23 +212,25 @@ class Roofline:
         }
 
 
-def analyze(compiled, model_flops: float, n_devices: int) -> dict:
-    """Roofline terms from the compiled artifact.
+def analyze(compiled, model_flops: float, n_devices: int,
+            device_kind: str) -> dict:
+    """Roofline terms from the compiled artifact, priced for a chip of
+    ``device_kind`` (see ``PEAKS``).
 
     FLOPs/bytes come from the HLO-text cost model (launch.hlo_cost) because
     XLA's cost_analysis visits while bodies once — layer scans would be
     undercounted x n_layers. The raw cost_analysis numbers are recorded for
     reference.
     """
-    from ..compat import cost_analysis
     from .hlo_cost import HloModule
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     mod = HloModule(compiled.as_text())
     flops = float(max(mod.flops(), float(cost.get("flops", 0.0))))
     byts = float(max(mod.bytes_accessed(),
                      float(cost.get("bytes accessed", 0.0))))
     coll = mod.collective_bytes()
-    rl = Roofline(flops, byts, coll["total_bytes"], n_devices, model_flops)
+    rl = Roofline(flops, byts, coll["total_bytes"], n_devices, device_kind,
+                  model_flops)
     mem = compiled.memory_analysis()
     return {
         "roofline": rl.to_dict(),

@@ -22,7 +22,7 @@ jax.config.update("jax_enable_x64", True)  # engine vectors are fp64
 import numpy as np  # noqa: E402
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="wikipedia",
                     help="paper dataset name or 'synthetic'")
@@ -40,10 +40,25 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--topk", type=int, default=10)
-    args = ap.parse_args()
+    return ap
+
+
+def make_engine(g, args):
+    """The ``RankingEngine`` the launcher runs over ``g``, from its flags."""
+    from ..core.engine import RankingEngine
+    return RankingEngine(g, args.algorithm, n_shards=args.shards,
+                         stale_limit=args.stale_limit,
+                         straggler_prob=args.straggler_prob,
+                         checkpoint_dir=args.ckpt,
+                         checkpoint_every=args.ckpt_every)
+
+
+def main():
+    args = build_parser().parse_args()
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from ..core import back_button
-    from ..core.engine import RankingEngine
     from ..graph import WebGraphSpec, generate_webgraph, paper_dataset
 
     if args.dataset == "synthetic":
@@ -57,11 +72,7 @@ def main():
         g = back_button(g)
         print(f"back-button: E={g.n_edges} dangling={g.dangling_fraction():.1%}")
 
-    eng = RankingEngine(g, args.algorithm, n_shards=args.shards,
-                        stale_limit=args.stale_limit,
-                        straggler_prob=args.straggler_prob,
-                        checkpoint_dir=args.ckpt,
-                        checkpoint_every=args.ckpt_every)
+    eng = make_engine(g, args)
     t0 = time.time()
     res = eng.run(tol=args.tol, resume=args.resume)
     dt = time.time() - t0

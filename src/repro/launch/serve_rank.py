@@ -95,7 +95,9 @@ def zipf_query_stream(rng, n_nodes: int, n_queries: int, roots_per_query: int,
     return [vocab_sets[i] for i in picks]
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
+    """The launcher's flags; their defaults are the served configuration
+    (``configs/hits_webgraph.CONFIG``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="wikipedia",
                     help="paper dataset name or 'synthetic'")
@@ -194,10 +196,40 @@ def main():
                     help="serve GET /healthz and /stats.json on this "
                          "loopback port (0: ephemeral, printed at start; "
                          "omit to disable)")
-    args = ap.parse_args()
+    return ap
+
+
+def service_config(args, spill=None):
+    """The ``RankServiceConfig`` the launcher serves with, from its flags."""
+    from ..serve import RankServiceConfig
+    return RankServiceConfig(v_max=args.v, tol=args.tol,
+                             backend=args.backend,
+                             shard_mode=args.shard_mode,
+                             shard_devices=args.shard_devices,
+                             plan_cache_size=args.plan_cache,
+                             bsr_fused=not args.bsr_host_loop,
+                             pipeline_depth=args.pipeline_depth,
+                             sweep_dtype=args.sweep_dtype,
+                             polish_tol=args.polish_tol or None,
+                             lumping=args.lumping,
+                             rank_k=args.rank_k,
+                             stable_sweeps=args.stable_sweeps,
+                             deadline_ms=args.deadline_ms,
+                             queue_depth=args.queue_depth,
+                             shed_priority=args.shed_priority,
+                             spill_dir=spill,
+                             spill_policy=args.spill_policy,
+                             spill_keep_generations=args
+                             .spill_keep_generations)
+
+
+def main():
+    args = build_parser().parse_args()
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from ..graph import WebGraphSpec, generate_webgraph, paper_dataset
-    from ..serve import RankService, RankServiceConfig
+    from ..serve import RankService
 
     if args.dataset == "synthetic":
         g = generate_webgraph(WebGraphSpec(args.n_nodes, args.n_edges,
@@ -207,28 +239,7 @@ def main():
     print(f"graph: N={g.n_nodes} E={g.n_edges} "
           f"dangling={g.dangling_fraction():.1%}")
 
-    def cfg(spill=args.spill_dir):
-        return RankServiceConfig(v_max=args.v, tol=args.tol,
-                                 backend=args.backend,
-                                 shard_mode=args.shard_mode,
-                                 shard_devices=args.shard_devices,
-                                 plan_cache_size=args.plan_cache,
-                                 bsr_fused=not args.bsr_host_loop,
-                                 pipeline_depth=args.pipeline_depth,
-                                 sweep_dtype=args.sweep_dtype,
-                                 polish_tol=args.polish_tol or None,
-                                 lumping=args.lumping,
-                                 rank_k=args.rank_k,
-                                 stable_sweeps=args.stable_sweeps,
-                                 deadline_ms=args.deadline_ms,
-                                 queue_depth=args.queue_depth,
-                                 shed_priority=args.shed_priority,
-                                 spill_dir=spill,
-                                 spill_policy=args.spill_policy,
-                                 spill_keep_generations=args
-                                 .spill_keep_generations)
-
-    svc = RankService(g, cfg())
+    svc = RankService(g, service_config(args, spill=args.spill_dir))
     if args.spill_dir and svc.stats["spill_restored"]:
         print(f"spill: restored {svc.stats['spill_restored']} cache entries "
               f"from {args.spill_dir}")
@@ -238,7 +249,7 @@ def main():
 
     # warm the compile caches so the loop measures serving, not tracing
     # (on a fresh service so the measured run's cache starts cold)
-    RankService(g, cfg(spill=None)).rank(stream[: args.v])
+    RankService(g, service_config(args)).rank(stream[: args.v])
 
     # ops surface: loopback health/stats endpoint + graceful drain state
     # (docs/OPERATIONS.md documents both contracts)

@@ -10,8 +10,6 @@ from __future__ import annotations
 import jax
 from jax.sharding import PartitionSpec as P
 
-from ..compat import get_abstract_mesh
-
 DP = ("pod", "data")  # canonical data-parallel axes (outermost first)
 
 
@@ -26,8 +24,8 @@ def _filter_axis(a, names):
 
 def shard_hint(x, *spec):
     """with_sharding_constraint if a mesh is active; identity otherwise."""
-    mesh = get_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
     names = set(mesh.axis_names)
     clean = tuple(_filter_axis(a, names) for a in spec)

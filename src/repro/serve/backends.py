@@ -51,8 +51,8 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..compat import make_mesh, set_mesh
 from ..core.hits import EdgeList, hits_sweep_cols
 from ..core.reordering import blocking_permutation
 from ..graph.structure import Graph
@@ -381,8 +381,8 @@ _SHARDED_JIT: Dict[tuple, object] = {}
 
 # process-wide mesh per (device subset, axes): meshes are pure structure,
 # so every backend instance (and every plan) over the same device subset
-# shares ONE object — repeat batches and fresh services alike never pay
-# compat.make_mesh again, and mesh-keyed jit caches keep hitting
+# shares ONE object — repeat batches and fresh services alike never build
+# a mesh again, and mesh-keyed jit caches keep hitting
 _MESH_CACHE: Dict[tuple, object] = {}
 
 
@@ -390,7 +390,7 @@ def shared_mesh(devices, axes):
     key = (tuple(d.id for d in devices), tuple(axes))
     mesh = _MESH_CACHE.get(key)
     if mesh is None:
-        mesh = make_mesh((len(devices),), tuple(axes), devices=devices)
+        mesh = jax.sharding.Mesh(np.asarray(devices), tuple(axes))
         _MESH_CACHE[key] = mesh
     return mesh
 
@@ -487,6 +487,14 @@ class ShardedSweepBackend(SweepBackend):
         self.n_shards = s
         self.axes = (axis,)
         self.mesh = shared_mesh(devices[:s], self.axes)
+        # edge planes split along their leading shard axis (shard s lives
+        # on device s); per-column vectors are replicated on every device
+        self._edges = NamedSharding(self.mesh, P(axis, None))
+        self._blocked = NamedSharding(self.mesh, P(axis, None, None))
+        self._replicated = NamedSharding(self.mesh, P())
+
+    def _put(self, x, sharding, dtype=None):
+        return jax.device_put(np.asarray(x, dtype), sharding)
 
     def collective_bytes_per_sweep(self, n_pad: int, v: int,
                                    itemsize: int = 8) -> int:
@@ -508,7 +516,8 @@ class ShardedSweepBackend(SweepBackend):
                            n_pad=n_pad, mesh=self.mesh, mode=self.mode,
                            n_shards=self.n_shards, per=shards["per"],
                            nb=int(shards.get("nb", 0)),
-                           eargs=device_put_edge_args_cols(shards, b.dtype))
+                           eargs=device_put_edge_args_cols(
+                               shards, b.dtype, self._edges))
 
     def plan_arrays(self, plan: ShardedPlan):
         # the eargs tuple IS the layout (calling-convention order owned by
@@ -524,7 +533,7 @@ class ShardedSweepBackend(SweepBackend):
         if meta["mode"] != self.mode or int(meta["n_shards"]) != self.n_shards:
             raise ValueError("spilled plan laid out for a different "
                              f"shard config: {meta}")
-        eargs = tuple(jnp.asarray(arrays[f"earg{i}"])
+        eargs = tuple(self._put(arrays[f"earg{i}"], self._edges)
                       for i in range(int(meta["n_eargs"])))
         return ShardedPlan(key=key, backend=self.name,
                            n_pad=int(meta["n_pad"]), mesh=self.mesh,
@@ -554,11 +563,15 @@ class ShardedSweepBackend(SweepBackend):
                 or int(shards.get("nb", 0)) != plan.nb:
             return None
         e = plan.eargs
+
+        def put_w(w):
+            return self._put(w, self._edges, b.dtype)
+
         if plan.mode == "replicated":
-            eargs = (e[0], e[1], jnp.asarray(shards["w"], b.dtype))
+            eargs = (e[0], e[1], put_w(shards["w"]))
         else:
-            eargs = (e[0], e[1], jnp.asarray(shards["a"]["w"], b.dtype),
-                     e[3], e[4], jnp.asarray(shards["h"]["w"], b.dtype))
+            eargs = (e[0], e[1], put_w(shards["a"]["w"]),
+                     e[3], e[4], put_w(shards["h"]["w"]))
         return ShardedPlan(key=key or b.structure_key(), backend=self.name,
                            n_pad=plan.n_pad, mesh=plan.mesh, mode=plan.mode,
                            n_shards=plan.n_shards, per=plan.per, nb=plan.nb,
@@ -569,18 +582,20 @@ class ShardedSweepBackend(SweepBackend):
 
         dual_blocked pads node rows to nb*S >= n_pad — non-pow2 device
         counts get dead extra rows (zero weights/mask/h0), like the
-        service's pad row — and iterates h in (S, nb, V) blocked form.
+        service's pad row — and iterates h in (S, nb, V) blocked form,
+        block s on device s.
         """
+        rep = self._replicated
         if plan.mode == "replicated":
-            return (jnp.asarray(h0, dtype), jnp.asarray(ca, dtype),
-                    jnp.asarray(ch, dtype), jnp.asarray(m, dtype))
+            return tuple(self._put(x, rep, dtype) for x in (h0, ca, ch, m))
         nb = plan.nb
         n_rows, v = np.shape(h0)
         rows = ((0, nb * plan.n_shards - n_rows), (0, 0))
         h0, ca, ch, m = (np.pad(np.asarray(x), rows) for x in (h0, ca, ch, m))
-        return (jnp.asarray(h0.reshape(plan.n_shards, nb, v), dtype),
-                jnp.asarray(ca, dtype), jnp.asarray(ch, dtype),
-                jnp.asarray(m, dtype))
+        return (self._put(h0.reshape(plan.n_shards, nb, v), self._blocked,
+                          dtype),
+                self._put(ca, rep, dtype), self._put(ch, rep, dtype),
+                self._put(m, rep, dtype))
 
     def sweep(self, plan: ShardedPlan, b: SweepBatch):
         self._check(plan, b)
@@ -592,7 +607,7 @@ class ShardedSweepBackend(SweepBackend):
                                rank_k=int(b.rank_k),
                                stable_sweeps=int(b.stable_sweeps),
                                bulk_dtype=b.ladder_key() or None)
-        with set_mesh(plan.mesh):
+        with jax.set_mesh(plan.mesh):
             h, a, conv, res = fn(h0, ca, ch, m, plan.eargs, b.tol,
                                  b.bulk_tol())
         h = np.asarray(h).reshape(-1, v)[:n_pad]
@@ -612,7 +627,7 @@ class ShardedSweepBackend(SweepBackend):
                                             zeros, dtype)
         smapped = make_dist_hits_sweep_cols(plan.mesh, self.mode, n_pad,
                                             axes=self.axes)
-        with set_mesh(plan.mesh):
+        with jax.set_mesh(plan.mesh):
             compiled = jax.jit(smapped).lower(h0, ca, ch, m,
                                               *plan.eargs).compile()
         return wire_bytes_from_collectives(
@@ -651,6 +666,7 @@ class BsrSweepBackend(SweepBackend):
     def plan(self, b: SweepBatch, key: str = "") -> BsrPlan:
         """Blocking permutation + both BSR structures — the expensive
         host-side layout work (two block builds) repeat batches skip."""
+        check_bsr_dtype(b.dtype, self.interpret)
         n_pad = b.h0.shape[0]
         real = np.asarray(b.w) != 0  # drop sentinel padding edges
         src, dst = np.asarray(b.src)[real], np.asarray(b.dst)[real]
@@ -840,15 +856,16 @@ class BsrSweepBackend(SweepBackend):
 
 def select_backend(n_union: int, e_union: int,
                    n_devices: Optional[int] = None,
-                   pallas_compiled: Optional[bool] = None) -> str:
-    """The ``auto`` heuristic: pick a backend from subgraph density and
-    device count.
+                   pallas_compiled: Optional[bool] = None, *,
+                   dtype) -> str:
+    """The ``auto`` heuristic: pick a backend from subgraph density,
+    device count and sweep dtype.
 
     Multi-device meshes shard once the union subgraph carries enough edges
     to amortize per-sweep collectives; single-device dense-block subgraphs
     take the Pallas BSR path when it actually compiles (TPU — interpreter
-    mode would serve slower than the XLA dense path); everything else stays
-    dense.
+    mode would serve slower than the XLA dense path) and the sweep dtype
+    is one Mosaic has (no f64); everything else stays dense.
     """
     if n_devices is None:
         n_devices = len(jax.devices())
@@ -856,9 +873,21 @@ def select_backend(n_union: int, e_union: int,
         pallas_compiled = not resolve_interpret(None)
     if n_devices > 1 and e_union >= _SHARD_MIN_EDGES:
         return "sharded"
-    if pallas_compiled and e_union >= _BSR_MIN_EDGES_PER_NODE * max(n_union, 1):
+    if pallas_compiled and np.dtype(dtype) != np.float64 \
+            and e_union >= _BSR_MIN_EDGES_PER_NODE * max(n_union, 1):
         return "bsr"
     return "dense"
+
+
+def check_bsr_dtype(dtype, interpret: Optional[bool]):
+    """Refuse an f64 sweep on compiled Pallas: Mosaic has no float64, so
+    such a batch would fail at its first compile. Interpret mode (the CPU
+    default) runs f64 as it always has."""
+    if np.dtype(dtype) == np.float64 and not resolve_interpret(interpret):
+        raise ValueError(
+            "backend 'bsr' cannot sweep float64 with compiled Pallas "
+            "(Mosaic has no f64): use dtype=float32 (optionally with "
+            "sweep_dtype='bf16'), or backend 'dense' or 'auto'")
 
 
 def make_backend(kind: str, *, shard_mode: str = "dual_blocked",

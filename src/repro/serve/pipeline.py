@@ -98,6 +98,7 @@ class _Assembled:
     statuses: list                 # per-todo "warm" | "cold"
     locs: list                     # per-todo union-local index arrays
     backend: Any = None
+    union: tuple = (0, 0)          # union subgraph (nodes, edges), unpadded
     batch: Optional[SweepBatch] = None
     lump: Any = None               # LumpMap when the batch is lump-reduced
     plan: Any = None
@@ -224,6 +225,7 @@ class ServePipeline:
         union = svc.extractor.extract_union(subs)
         nodes_u = union.nodes
         n_u, e_u = len(nodes_u), union.graph.n_edges
+        asm.union = (n_u, e_u)
         n_pad = next_pow2(max(n_u + 1, 16))  # +1: a guaranteed-dead pad row
         e_pad = next_pow2(max(e_u, 16))
         V = svc.cfg.v_max
@@ -346,6 +348,8 @@ class ServePipeline:
             for j in range(len(asm.todo)):
                 svc._m_sweep_iters.observe(int(asm.conv[j]))
                 svc.telemetry.counter("service.exit", reasons[j]).inc()
+            svc._m_union_nodes.observe(asm.union[0])
+            svc._m_union_edges.observe(asm.union[1])
             if asm.batch.bulk_dtype is not None:
                 svc._m_ladder.inc()
             if asm.lump is not None:

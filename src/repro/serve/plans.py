@@ -177,6 +177,10 @@ class PlanCache:
         self.stats["hits"] += 1
         return plan
 
+    def plans(self) -> list:
+        """The cached plans, least recently used first (no LRU touch)."""
+        return list(self._plans.values())
+
     def peek(self, key: Optional[tuple]) -> Optional[SweepPlan]:
         """Hit/miss- and LRU-neutral lookup. The delta patch path probes
         for a predecessor plan with this; a failed probe is not a cache

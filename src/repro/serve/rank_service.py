@@ -171,7 +171,8 @@ class RankService:
             warnings.simplefilter("ignore")  # x64-truncation noise
             eff = jnp.zeros((), self.cfg.dtype).dtype
         self._dtype = eff
-        from .backends import dtype_floor, resolve_sweep_dtype
+        from .backends import (check_bsr_dtype, dtype_floor,
+                               resolve_sweep_dtype)
         min_tol = dtype_floor(eff)
         if self.cfg.tol < min_tol:
             warnings.warn(
@@ -209,6 +210,8 @@ class RankService:
         self._polish_tol = polish
         if self.cfg.backend not in ("dense", "sharded", "bsr", "auto"):
             raise ValueError(f"unknown backend {self.cfg.backend!r}")
+        if self.cfg.backend == "bsr":
+            check_bsr_dtype(eff, self.cfg.interpret)
         if self.cfg.rank_k < 0:
             raise ValueError(f"rank_k must be >= 0, got {self.cfg.rank_k}")
         if self.cfg.stable_sweeps < 1:
@@ -260,6 +263,10 @@ class RankService:
         # non-legacy families, registered eagerly so names() (and the
         # runbook consistency test) see the full set before traffic does
         self._m_sweep_iters = reg.histogram("service.sweep.iters")
+        # swept union subgraph size per batch, before padding: what sizes
+        # the device program (n_pad, e_pad buckets) and the plan
+        self._m_union_nodes = reg.histogram("service.union.nodes")
+        self._m_union_edges = reg.histogram("service.union.edges")
         for reason in ("residual", "rank_stable", "max_iter"):
             reg.counter("service.exit", reason)
         if self.cfg.backend != "auto":  # auto resolves per batch
@@ -332,7 +339,8 @@ class RankService:
             from ..kernels import resolve_interpret
             kind = select_backend(
                 n_union, e_union, n_devices=self.cfg.shard_devices,
-                pallas_compiled=not resolve_interpret(self.cfg.interpret))
+                pallas_compiled=not resolve_interpret(self.cfg.interpret),
+                dtype=self._dtype)
         be = self._backends.get(kind)
         if be is None:
             be = make_backend(kind, shard_mode=self.cfg.shard_mode,

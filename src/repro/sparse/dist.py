@@ -24,7 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..compat import axis_size, shard_map
 from ..graph.partition import partition_edges, partition_edges_by_dst_block
 from ..graph.structure import Graph, next_pow2
 
@@ -77,7 +76,7 @@ def _flat_axis_index(axes):
     """Flattened shard index across possibly-multiple mesh axes."""
     idx = jax.lax.axis_index(axes[0])
     for a in axes[1:]:
-        idx = idx * axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -114,7 +113,7 @@ def make_dist_hits_sweep(mesh, shards, n: int, axes=("data",),
                                      keepdims=h.ndim > 1) + 1e-30)
             return h_new, a
 
-        smapped = shard_map(
+        smapped = jax.shard_map(
             sweep, mesh=mesh,
             in_specs=(P(), espec, espec, espec, espec),
             out_specs=(P(), P()),
@@ -145,7 +144,7 @@ def make_dist_hits_sweep(mesh, shards, n: int, axes=("data",),
             h_new_blk = h_new_blk / (tot + 1e-30)
             return h_new_blk[None], a_blk[None]
 
-        smapped = shard_map(
+        smapped = jax.shard_map(
             sweep, mesh=mesh,
             in_specs=(espec,) + (espec,) * 8,
             out_specs=(espec, espec),
@@ -176,7 +175,7 @@ def make_dist_hits_sweep(mesh, shards, n: int, axes=("data",),
             h_new_blk = h_new_blk / (tot + 1e-30)
             return h_new_blk[None], a_blk[None]
 
-        smapped = shard_map(
+        smapped = jax.shard_map(
             sweep, mesh=mesh,
             in_specs=(espec,) + (espec,) * 8,
             out_specs=(espec, espec),
@@ -274,24 +273,28 @@ def build_edge_shards_cols(src, dst, w, n_pad: int, n_shards: int,
     raise ValueError(mode)
 
 
-def device_put_edge_args_cols(shards, dtype):
-    """Ship ``build_edge_shards_cols`` output to the device as the sweep's
+def device_put_edge_args_cols(shards, dtype, sharding):
+    """Ship ``build_edge_shards_cols`` output to the devices as the sweep's
     edge-argument tuple, in calling-convention order.
 
     This is the single owner of that ordering — ((src, dst, w) for
     ``replicated``; (asrc, adst, aw, hsrc, hdst, hw) for ``dual_blocked``)
     — and the piece the serve plan cache keeps device-resident, so repeat
     batches over the same union subgraph skip both the host-side
-    partition and the host->device transfer.
+    partition and the host->device transfer. ``sharding`` splits each
+    (S, per) plane along its shard axis, so shard s lives on device s.
     """
+    def put(x, dt=None):
+        return jax.device_put(np.asarray(x, dt), sharding)
+
     if shards["mode"] == "replicated":
-        return (jnp.asarray(shards["src"]), jnp.asarray(shards["dst"]),
-                jnp.asarray(shards["w"], dtype))
+        return (put(shards["src"]), put(shards["dst"]),
+                put(shards["w"], dtype))
     if shards["mode"] == "dual_blocked":
         eargs = ()
         for part in (shards["a"], shards["h"]):
-            eargs += (jnp.asarray(part["src"]), jnp.asarray(part["dst"]),
-                      jnp.asarray(part["w"], dtype))
+            eargs += (put(part["src"]), put(part["dst"]),
+                      put(part["w"], dtype))
         return eargs
     raise ValueError(shards["mode"])
 
@@ -326,7 +329,7 @@ def make_dist_hits_sweep_cols(mesh, mode: str, n_pad: int, axes=("data",)):
                              + 1e-30)
             return h_new, a
 
-        return shard_map(
+        return jax.shard_map(
             sweep, mesh=mesh,
             in_specs=(P(), P(), P(), P(), espec, espec, espec),
             out_specs=(P(), P()))
@@ -349,7 +352,7 @@ def make_dist_hits_sweep_cols(mesh, mode: str, n_pad: int, axes=("data",)):
             h_new_blk = h_new_blk / (tot + 1e-30)
             return h_new_blk[None], a_blk[None]
 
-        return shard_map(
+        return jax.shard_map(
             sweep, mesh=mesh,
             in_specs=(bspec, P(), P(), P()) + (espec,) * 6,
             out_specs=(bspec, bspec))
@@ -435,7 +438,7 @@ def make_dryrun_rank_sweep(mesh, n: int, axes, mode: str = "baseline",
             h_new_blk = (h_new_blk / (tot + 1e-30)).astype(dt)
             return h_new_blk[None], a_blk[None]
 
-        return shard_map(sweep, mesh=mesh,
+        return jax.shard_map(sweep, mesh=mesh,
                              in_specs=(espec,) + (espec,) * 8,
                              out_specs=(espec, espec))
 
@@ -453,7 +456,7 @@ def make_dryrun_rank_sweep(mesh, n: int, axes, mode: str = "baseline",
         h_new = (h_new.astype(jnp.float32) / (tot + 1e-30)).astype(dt)
         return h_new, a
 
-    return shard_map(sweep, mesh=mesh,
+    return jax.shard_map(sweep, mesh=mesh,
                          in_specs=(P(), espec, espec, espec, espec),
                          out_specs=(P(), P()))
 
@@ -468,7 +471,7 @@ def ring_allreduce_chunked(x, axis: str, n_chunks: int = 4):
     overlap chunk k+1's adds under XLA's async collective scheduler.
     Semantics == lax.psum(x, axis). Used by the overlap §Perf experiment.
     """
-    s = axis_size(axis)
+    s = jax.lax.axis_size(axis)
     if s == 1:
         return x
     pad = (-x.shape[0]) % (n_chunks * s)

@@ -10,7 +10,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..compat import axis_size
 
 
 def init_error_state(params):
@@ -58,7 +57,7 @@ def ef_compressed_psum(grads, err_state, axis_name: str):
     flat_g, td = jax.tree.flatten(grads)
     flat_e = jax.tree.leaves(err_state)
     outs, errs = [], []
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     for g, e in zip(flat_g, flat_e):
         g_corr = g.astype(jnp.float32) + e
         scale = jax.lax.pmax(jnp.max(jnp.abs(g_corr)), axis_name) / 127.0 + 1e-30

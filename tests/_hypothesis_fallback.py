@@ -5,8 +5,8 @@ absent, so the property-test modules collect and still exercise their
 properties. The fallback draws a fixed number of deterministic
 pseudo-random examples per test (seeded rng — reproducible across runs);
 there is no shrinking and no database. Implements exactly the surface this
-repo's tests use: ``given``, ``settings``, and the ``strategies``
-``integers`` / ``floats`` / ``lists`` / ``tuples``.
+repo's tests use: ``given``, ``settings``, ``assume``, and the
+``strategies`` ``integers`` / ``floats`` / ``lists`` / ``tuples``.
 """
 from __future__ import annotations
 
@@ -45,6 +45,16 @@ def lists(elements, min_size=0, max_size=None):
     return _Strategy(draw)
 
 
+class _Rejected(Exception):
+    """An ``assume`` failed: the example is discarded, not failed."""
+
+
+def assume(condition):
+    if not condition:
+        raise _Rejected
+    return True
+
+
 def settings(max_examples=100, deadline=None, **_kw):
     def deco(fn):
         fn._fallback_max_examples = min(max_examples, FALLBACK_MAX_EXAMPLES)
@@ -63,7 +73,10 @@ def given(*strats, **kw_strats):
             for _ in range(n):
                 drawn = tuple(s.draw(rng) for s in strats)
                 drawn_kw = {k: s.draw(rng) for k, s in kw_strats.items()}
-                fn(*drawn, **drawn_kw)
+                try:
+                    fn(*drawn, **drawn_kw)
+                except _Rejected:
+                    continue
 
         runner.__name__ = fn.__name__
         runner.__qualname__ = getattr(fn, "__qualname__", fn.__name__)
@@ -80,6 +93,7 @@ def install():
     mod = types.ModuleType("hypothesis")
     mod.given = given
     mod.settings = settings
+    mod.assume = assume
     mod.HealthCheck = types.SimpleNamespace(too_slow=None, data_too_large=None)
     st = types.ModuleType("hypothesis.strategies")
     st.integers = integers

@@ -1,22 +1,22 @@
 """Fused on-device BSR convergence (kernels.bsr_converge_cols) vs the
-host-driven loop (ISSUE 4).
+host-driven loop.
 
 The fused path runs ``lax.while_loop`` around the Pallas sweep with the
 tolerance check in the carry — one device dispatch per batch. The
 host-driven loop (``BsrSweepBackend(fused=False)``) is the semantic
 reference: both must agree on the fixed-point vectors (<=1e-10 L1) and the
 per-column sweep counts (+-1), through max-iteration cutoffs and
-already-converged warm starts, in interpret and (on TPU) compiled mode.
+already-converged warm starts, in interpret mode here
+(``tests/test_tpu_compile.py`` compiles the same loop for the TPU).
 """
 import os
 import subprocess
 import sys
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis import given, settings, strategies as st
+from hypothesis import assume, given, settings, strategies as st
 
 from repro.core.weights import accel_weights
 from repro.graph.structure import next_pow2
@@ -61,6 +61,23 @@ def make_batch(seed, n, v, tol=1e-10, max_iter=200, h0=None):
                       tol=tol, max_iter=max_iter, dtype=jnp.float64)
 
 
+def spectral_ratios(batch):
+    """Per column, |lambda_2| / lambda_1 of the masked one-sweep operator
+    L·diag(ca)·Lᵀ·diag(ch): the rate at which power iteration sheds
+    everything but the fixed point."""
+    n = batch.h0.shape[0]
+    adj = np.zeros((n, n))
+    np.add.at(adj, (batch.src, batch.dst), batch.w)
+    out = []
+    for j in range(batch.h0.shape[1]):
+        m = batch.mask[:, j]
+        lm = adj * m[:, None] * m[None, :]
+        op = lm @ (batch.ca[:, j, None] * lm.T) * batch.ch[None, :, j]
+        ev = np.sort(np.abs(np.linalg.eigvals(op)))[::-1]
+        out.append(ev[1] / ev[0])
+    return np.array(out)
+
+
 def fused_and_host(batch, bs=32):
     fused = BsrSweepBackend(bs=bs, fused=True).converge(batch)
     host = BsrSweepBackend(bs=bs, fused=False).converge(batch)
@@ -82,11 +99,17 @@ def assert_agree(fused, host, iter_slack=1):
 @settings(max_examples=8, deadline=None)
 def test_fused_matches_host_loop(seed, v, n):
     """Fixed-point vectors <=1e-10 L1 apart, sweep counts within +-1, on
-    random graphs x random column masks."""
+    random graphs x random column masks; every well-posed column converges
+    within max_iter."""
     batch = make_batch(seed, n, v)
     fused, host = fused_and_host(batch)
     assert_agree(fused, host)
-    # every column actually converged (the batch is well-posed)
+    # well-posed: each column's spectral ratio lets max_iter sweeps shrink
+    # the error well below tol. A draw with two near-equal dominant
+    # components (the paper: the fixed point need not be unique, and close
+    # to that it is slow) is checked for agreement above and then set aside
+    rate = spectral_ratios(batch) ** batch.max_iter
+    assume((rate <= batch.tol / 100).all())
     assert (fused[2] < batch.max_iter).all()
 
 
@@ -218,8 +241,8 @@ print("ENV_MODE OK", os.environ.get("REPRO_PALLAS_INTERPRET", "<auto>"))
 def test_interpret_env_override_modes(env_val):
     """REPRO_PALLAS_INTERPRET must steer the fused loop exactly like the
     per-call kernels: forced-interpreter and auto mode both converge and
-    agree with the host loop (compiled Mosaic needs TPU; on TPU hosts the
-    auto leg exercises it)."""
+    agree with the host loop (the compiled mode is covered, without a
+    chip, by tests/test_tpu_compile.py)."""
     env = dict(os.environ, PYTHONPATH="src")
     if env_val is None:
         env.pop("REPRO_PALLAS_INTERPRET", None)
@@ -232,13 +255,40 @@ def test_interpret_env_override_modes(env_val):
     assert "ENV_MODE OK" in r.stdout
 
 
-def test_compiled_mode_on_tpu_only():
-    """Explicit compiled mode (REPRO_PALLAS_INTERPRET=0) — the TPU serving
-    configuration the fused loop exists for."""
-    if jax.default_backend() != "tpu":
-        pytest.skip("compiled Pallas path needs a TPU backend")
-    env = dict(os.environ, PYTHONPATH="src", REPRO_PALLAS_INTERPRET="0")
-    r = subprocess.run([sys.executable, "-c", INTERPRET_ENV],
-                       capture_output=True, text=True, env=env, cwd=ROOT,
-                       timeout=600)
-    assert r.returncode == 0, (r.stdout[-1000:], r.stderr[-3000:])
+# ------------------------------------------- f64 never reaches compiled Pallas
+
+
+def test_compiled_bsr_refuses_f64_at_construction():
+    """Mosaic has no f64: an explicit bsr service at f64 with compiled
+    Pallas is refused when it is built, and so is a bare backend's plan;
+    f32 (with or without the bf16 ladder) and interpret mode still build."""
+    from repro.graph import WebGraphSpec, generate_webgraph
+    from repro.serve import RankService, RankServiceConfig
+
+    g = generate_webgraph(WebGraphSpec(260, 2000, 0.5, seed=2))
+    with pytest.raises(ValueError, match="float64"):
+        RankService(g, RankServiceConfig(backend="bsr", interpret=False))
+    RankService(g, RankServiceConfig(backend="bsr", interpret=False,
+                                     dtype=jnp.float32, tol=1e-4))
+    RankService(g, RankServiceConfig(backend="bsr", interpret=False,
+                                     dtype=jnp.float32, tol=1e-4,
+                                     sweep_dtype="bf16"))
+    RankService(g, RankServiceConfig(backend="bsr", interpret=True))
+    with pytest.raises(ValueError, match="float64"):
+        BsrSweepBackend(bs=32, interpret=False).plan(make_batch(1, 40, 2))
+
+
+def test_auto_keeps_f64_off_compiled_pallas():
+    """``auto`` with compiled Pallas routes a dense-block f64 union to the
+    dense backend, not to bsr, and serves it."""
+    from repro.graph import WebGraphSpec, generate_webgraph
+    from repro.serve import RankService, RankServiceConfig
+
+    g = generate_webgraph(WebGraphSpec(200, 6000, 0.1, seed=3))
+    svc = RankService(g, RankServiceConfig(v_max=4, backend="auto",
+                                           interpret=False,
+                                           shard_devices=1))
+    rng = np.random.default_rng(0)
+    svc.rank([rng.choice(g.n_nodes, size=4, replace=False)
+              for _ in range(4)])
+    assert svc.snapshot_stats()["backend_batches"] == {"dense": 1}

@@ -27,13 +27,13 @@ from repro.core import accel_hits, accel_weights
 g = generate_webgraph(WebGraphSpec(200, 1500, 0.6, seed=1))
 ref = accel_hits(g, tol=1e-12, dtype=jnp.float64)
 ca, ch = accel_weights(g.indeg(), g.outdeg())
-from repro.compat import make_mesh, set_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((4, 2), ("data", "model"))
 for mode in ["replicated", "dual_blocked", "dual_blocked_compact"]:
     shards = build_edge_shards(g, 8, mode)
     sweep, h0, args = make_dist_hits_sweep(mesh, shards, g.n_nodes,
         axes=("data", "model"), ca=ca, ch=ch, dtype=jnp.float64)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         sweep_j = jax.jit(sweep)
         h = h0
         for _ in range(60):
@@ -56,14 +56,14 @@ import numpy as np, jax, jax.numpy as jnp
 jax.config.update("jax_enable_x64", True)
 from jax.sharding import PartitionSpec as P
 from repro.sparse.dist import ring_allreduce_chunked
-from repro.compat import make_mesh, set_mesh, shard_map
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((8,), ("data",))
-f1 = shard_map(lambda xs: ring_allreduce_chunked(xs[0], "data", 3)[None],
+f1 = jax.shard_map(lambda xs: ring_allreduce_chunked(xs[0], "data", 3)[None],
                mesh=mesh, in_specs=P("data", None), out_specs=P("data", None))
-f2 = shard_map(lambda xs: jax.lax.psum(xs[0], "data")[None],
+f2 = jax.shard_map(lambda xs: jax.lax.psum(xs[0], "data")[None],
                mesh=mesh, in_specs=P("data", None), out_specs=P("data", None))
 x = jax.random.normal(jax.random.key(0), (8, 53), jnp.float64)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     assert np.allclose(jax.jit(f1)(x), jax.jit(f2)(x))
 print("RING OK")
 """
@@ -72,14 +72,14 @@ EF_PSUM = r"""
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.train.compression import ef_compressed_psum
-from repro.compat import make_mesh, set_mesh, shard_map
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((8,), ("d",))
 def f(gs):
     out, err = ef_compressed_psum({"g": gs[0]}, {"g": jnp.zeros_like(gs[0])}, "d")
     return out["g"][None]
-sm = shard_map(f, mesh=mesh, in_specs=P("d", None), out_specs=P("d", None))
+sm = jax.shard_map(f, mesh=mesh, in_specs=P("d", None), out_specs=P("d", None))
 x = jax.random.normal(jax.random.key(1), (8, 256), jnp.float32)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     got = np.asarray(jax.jit(sm)(x))[0]
 want = np.asarray(x).mean(0)
 rel = np.abs(got - want).max() / (np.abs(want).max() + 1e-9)
@@ -97,13 +97,14 @@ from repro.launch.steps import build_step
 from repro.launch.dryrun import _to_named
 from repro.launch import hlo_analysis
 # production code path on a small mesh: lower+compile+analyze one LM cell
-from repro.compat import make_mesh, set_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((2, 4), ("data", "model"))
 spec = get_spec("minitron-4b")
 step = build_step(spec, "train_4k")
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     compiled = jax.jit(step.fn, in_shardings=_to_named(step.in_specs, mesh, step.args)).lower(*step.args).compile()
-    out = hlo_analysis.analyze(compiled, step.meta["model_flops_per_step"], 8)
+    out = hlo_analysis.analyze(compiled, step.meta["model_flops_per_step"], 8,
+                               "TPU v5 lite")
 rl = out["roofline"]
 assert rl["flops_per_device"] > 0 and rl["hbm_bytes_per_device"] > 0
 assert rl["collective_bytes_per_device"] > 0  # TP must communicate

@@ -42,8 +42,7 @@ def test_hlo_cost_matches_xla_loop_free():
     C = jax.ShapeDtypeStruct((512, 64), jnp.float32)
     comp = jax.jit(f).lower(A, B, C).compile()
     mod = HloModule(comp.as_text())
-    from repro.compat import cost_analysis
-    ca = cost_analysis(comp)
+    ca = comp.cost_analysis()
     assert abs(mod.flops() - ca["flops"]) / ca["flops"] < 0.05
     assert abs(mod.bytes_accessed() - ca["bytes accessed"]) / \
         ca["bytes accessed"] < 0.2
@@ -79,10 +78,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.launch.hlo_cost import HloModule
-from repro.compat import make_mesh, shard_map
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((4,), ("d",))
 def f(x):
-    return shard_map(lambda xs: jax.lax.psum(xs, "d"), mesh=mesh,
+    return jax.shard_map(lambda xs: jax.lax.psum(xs, "d"), mesh=mesh,
                      in_specs=P("d", None), out_specs=P())(x)
 X = jax.ShapeDtypeStruct((8, 128), jnp.float32)
 comp = jax.jit(f).lower(X).compile()
@@ -97,3 +96,19 @@ print("OK", cb["total_bytes"])
                            os.path.dirname(os.path.abspath(__file__))))
     assert r.returncode == 0, r.stderr[-2000:]
     assert "OK" in r.stdout
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """Peaks come from the table for the named chip; a chip that is not in
+    the table is an error, never a silent v5e default."""
+    import pytest
+
+    from repro.launch.hlo_analysis import Roofline, peaks
+    v5e = peaks("TPU v5 lite")
+    assert v5e["flops"] == 197e12 and v5e["hbm_bw"] == 819e9
+    rl = Roofline(197e12, 0.0, 0.0, 1, "TPU v5 lite", model_flops=197e12)
+    assert rl.compute_s == 1.0 and rl.bottleneck == "compute"
+    with pytest.raises(ValueError, match="cpu"):
+        peaks("cpu")
+    with pytest.raises(ValueError):
+        Roofline(1.0, 1.0, 0.0, 1, "TPU v9 imaginary")

@@ -182,18 +182,22 @@ def test_backend_parity(name, code):
 
 def test_select_backend_heuristic():
     from repro.serve import select_backend
+    f32 = np.float32
     # multi-device + big union subgraph -> sharded, regardless of pallas
     assert select_backend(4096, 80000, n_devices=8,
-                          pallas_compiled=False) == "sharded"
+                          pallas_compiled=False, dtype=f32) == "sharded"
     # single device, dense-block regime, compiled pallas -> bsr
     assert select_backend(256, 4000, n_devices=1,
-                          pallas_compiled=True) == "bsr"
+                          pallas_compiled=True, dtype=f32) == "bsr"
+    # ... but never at f64: Mosaic has no f64, so the sweep stays dense
+    assert select_backend(256, 4000, n_devices=1, pallas_compiled=True,
+                          dtype=np.float64) == "dense"
     # interpreter-mode pallas never wins over XLA dense
     assert select_backend(256, 4000, n_devices=1,
-                          pallas_compiled=False) == "dense"
+                          pallas_compiled=False, dtype=f32) == "dense"
     # small/sparse subgraphs stay dense even on a mesh
     assert select_backend(64, 200, n_devices=8,
-                          pallas_compiled=True) == "dense"
+                          pallas_compiled=True, dtype=f32) == "dense"
 
 
 def test_unknown_backend_rejected():

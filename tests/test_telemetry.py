@@ -332,6 +332,7 @@ def test_launcher_sigterm_drains_and_exits_zero(tmp_path):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     env["PYTHONUNBUFFERED"] = "1"
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"  # tests keep it off
     cmd = [sys.executable, "-m", "repro.launch.serve_rank",
            "--dataset", "synthetic", "--n-nodes", "300", "--n-edges", "2400",
            "--requests", "5000", "--arrival-qps", "100", "--v", "4",
