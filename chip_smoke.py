@@ -9,18 +9,19 @@ Phases, in one process; the first failure ends the run with a non-zero
 exit:
 
 A. The served path at the paper's largest graph: ``paper_dataset
-   ("stanford")`` (225,441 pages, 2,196,441 links) through
+   ("stanford")`` (Table 7: 225,441 pages, 2,196,441 links; the
+   generator's dedup keeps about 1.26M links) through
    ``RankService.queue()`` with the launcher's defaults (``backend=auto``,
    f64, V=8, caps 32/32), Zipf traffic of 5-root queries.
 B. The same service at Kleinberg's query-time sizes: root sets of t=200
    and 50 in-links per root. Kleinberg takes every out-link; the out-link
-   cap of 50 here is an assumption. At least two full V=8 batches, whose
-   unions reach about 10^5 nodes.
-C. Compiled Pallas BSR on ``paper_dataset("britannica")`` (average degree
-   47, so unions are dense): ``backend=bsr`` at f32 to the f32 residual
-   floor, without and with the bf16 ladder, the program that ran checked
-   for Mosaic's ``tpu_custom_call``; then ``auto`` at the default f64 on
-   the same graph, which must serve without entering the kernel.
+   cap of 50 here is an assumption. At least two full V=8 batches.
+C. Compiled Pallas BSR on ``paper_dataset("britannica")`` (the densest
+   stand-in, about 23 links per page after dedup): ``backend=bsr`` at f32
+   to the f32 residual floor, without and with the bf16 ladder, the
+   program that ran checked for Mosaic's ``tpu_custom_call``; then
+   ``auto`` at the default f64 on the same graph, which must serve without
+   entering the kernel.
 D. Whole-graph accelerated HITS, the paper's own computation, as
    ``launch/rank.py`` builds it, over the back-button stanford graph.
 

@@ -11,7 +11,6 @@ import jax
 import jax.numpy as jnp
 
 
-
 def init_error_state(params):
     return jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
 

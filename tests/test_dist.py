@@ -58,10 +58,11 @@ from jax.sharding import PartitionSpec as P
 from repro.sparse.dist import ring_allreduce_chunked
 from repro.launch.mesh import make_mesh
 mesh = make_mesh((8,), ("data",))
+spec = P("data", None)
 f1 = jax.shard_map(lambda xs: ring_allreduce_chunked(xs[0], "data", 3)[None],
-               mesh=mesh, in_specs=P("data", None), out_specs=P("data", None))
+                   mesh=mesh, in_specs=spec, out_specs=spec)
 f2 = jax.shard_map(lambda xs: jax.lax.psum(xs[0], "data")[None],
-               mesh=mesh, in_specs=P("data", None), out_specs=P("data", None))
+                   mesh=mesh, in_specs=spec, out_specs=spec)
 x = jax.random.normal(jax.random.key(0), (8, 53), jnp.float64)
 with jax.set_mesh(mesh):
     assert np.allclose(jax.jit(f1)(x), jax.jit(f2)(x))

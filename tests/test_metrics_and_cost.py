@@ -82,7 +82,7 @@ from repro.launch.mesh import make_mesh
 mesh = make_mesh((4,), ("d",))
 def f(x):
     return jax.shard_map(lambda xs: jax.lax.psum(xs, "d"), mesh=mesh,
-                     in_specs=P("d", None), out_specs=P())(x)
+                         in_specs=P("d", None), out_specs=P())(x)
 X = jax.ShapeDtypeStruct((8, 128), jnp.float32)
 comp = jax.jit(f).lower(X).compile()
 cb = HloModule(comp.as_text()).collective_bytes()
