@@ -18,6 +18,7 @@ product; the combine is a sum, so the engine tolerates:
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from functools import partial
 from typing import Optional
 
@@ -28,17 +29,24 @@ import numpy as np
 from .. import checkpoint as ckpt_mod
 from ..graph.partition import partition_edges
 from ..graph.structure import Graph
+from ..serve.telemetry import span
 from .weights import accel_weights
 
 
 @partial(jax.jit, static_argnames=("n",))
 def _partial_a(h_scaled, src, dst, w, n):
-    return jax.ops.segment_sum(jnp.take(h_scaled, src) * w, dst, num_segments=n)
+    with jax.named_scope("segsum.gather"):
+        x = jnp.take(h_scaled, src) * w
+    with jax.named_scope("segsum.scatter"):
+        return jax.ops.segment_sum(x, dst, num_segments=n)
 
 
 @partial(jax.jit, static_argnames=("n",))
 def _partial_h(a_scaled, src, dst, w, n):
-    return jax.ops.segment_sum(jnp.take(a_scaled, dst) * w, src, num_segments=n)
+    with jax.named_scope("segsum.gather"):
+        x = jnp.take(a_scaled, dst) * w
+    with jax.named_scope("segsum.scatter"):
+        return jax.ops.segment_sum(x, src, num_segments=n)
 
 
 @dataclasses.dataclass
@@ -52,6 +60,8 @@ class EngineResult:
 
 
 class RankingEngine:
+    _job_ids = itertools.count()  # the ``job`` id of each engine's spans
+
     def __init__(self, g: Graph, algorithm: str = "accel", n_shards: int = 8,
                  stale_limit: int = 0, straggler_prob: float = 0.0,
                  checkpoint_dir: Optional[str] = None,
@@ -65,21 +75,26 @@ class RankingEngine:
         self.ckpt_every = checkpoint_every
         self.dtype = dtype
         self.rng = np.random.default_rng(seed)
-        parts = partition_edges(g, n_shards)
-        self.shards = [
-            (jnp.asarray(parts["src"][s]), jnp.asarray(parts["dst"][s]),
-             jnp.asarray(parts["w"][s] * parts["mask"][s], dtype))
-            for s in range(n_shards)
-        ]
-        if algorithm == "accel":
-            ca, ch = accel_weights(g.indeg(), g.outdeg())
-            self.ca = jnp.asarray(ca, dtype)
-            self.ch = jnp.asarray(ch, dtype)
-        elif algorithm == "hits":
-            self.ca = None
-            self.ch = None
-        else:
+        if algorithm not in ("accel", "hits"):
             raise ValueError(algorithm)
+        self.job_id = next(RankingEngine._job_ids)
+        with span("engine.build", job=self.job_id):
+            with span("engine.partition"):
+                parts = partition_edges(g, n_shards)
+                host = [(parts["src"][s], parts["dst"][s],
+                         parts["w"][s] * parts["mask"][s])
+                        for s in range(n_shards)]
+                weights = (accel_weights(g.indeg(), g.outdeg())
+                           if algorithm == "accel" else None)
+            # the host side of the transfers; their device side is not
+            # waited for here
+            with span("engine.upload"):
+                self.shards = [(jnp.asarray(src), jnp.asarray(dst),
+                                jnp.asarray(w, dtype))
+                               for src, dst, w in host]
+                self.ca, self.ch = ((None, None) if weights is None else
+                                    (jnp.asarray(weights[0], dtype),
+                                     jnp.asarray(weights[1], dtype)))
 
     # ------------------------------------------------------------- internals
     def _sweep(self, h, cache_a, cache_h, staleness, force_fresh=False):
@@ -142,10 +157,12 @@ class RankingEngine:
             # once the residual dips below tol, confirm with fully-fresh
             # sweeps (no stale partials) — otherwise a shard stuck on its
             # cached product can fake convergence at the wrong point
-            h_new, a, ev = self._sweep(h, cache_a, cache_h, staleness,
-                                       force_fresh=confirming)
+            with span("engine.sweep", job=self.job_id, sweep=k):
+                h_new, a, ev = self._sweep(h, cache_a, cache_h, staleness,
+                                           force_fresh=confirming)
+                with span("engine.sync"):  # the sweep's one host sync
+                    delta = float(jnp.sum(jnp.abs(h_new - h)))
             stale_total += ev
-            delta = float(jnp.sum(jnp.abs(h_new - h)))
             residuals.append(delta)
             h = h_new
             if self.ckpt_dir and self.ckpt_every and k % self.ckpt_every == 0:

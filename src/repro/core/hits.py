@@ -9,6 +9,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -111,8 +112,11 @@ def hits_sweep_cols(edges: EdgeList, ca, ch, mask):
     """
 
     def sweep(h):
-        a = spmv_dst(h * ch, edges.src, edges.dst, edges.n, edges.w) * mask
-        h_new = spmv_src(a * ca, edges.src, edges.dst, edges.n, edges.w) * mask
+        with jax.named_scope("hits.authority"):
+            a = spmv_dst(h * ch, edges.src, edges.dst, edges.n, edges.w) * mask
+        with jax.named_scope("hits.hub"):
+            h_new = spmv_src(a * ca, edges.src, edges.dst, edges.n,
+                             edges.w) * mask
         return normalize_l1(h_new, axis=0), a
 
     return sweep

@@ -1,27 +1,33 @@
-from .backends import (BACKENDS, BsrSweepBackend, DenseSweepBackend,
-                       ShardedSweepBackend, SweepBackend, SweepBatch,
-                       make_backend, select_backend, shared_mesh)
-from .kvquant import (dequantize_kv, init_quant_cache, quant_decode_attention,
-                      quantize_kv, update_quant_cache)
-from .pipeline import PipelineJob, ServePipeline
-from .plans import (BsrPlan, DensePlan, PlanCache, ShardedPlan, SweepPlan,
-                    structure_key)
-from .queue import QueueTicket, RankQueue
-from .rank_service import (QueryResult, RankService, RankServiceConfig)
-from .spill import CacheSpill, PlanSpill
-from .telemetry import (Counter, Gauge, Histogram, MetricsRegistry,
-                        StatsServer)
+"""The query-ranking service. Names load from their modules on first use,
+so that importing one light module (``serve.telemetry``, which the
+whole-graph engine uses for its spans) does not import the sweep
+backends and, through them, Pallas."""
+import importlib
 
-__all__ = [
-    "dequantize_kv", "init_quant_cache", "quant_decode_attention",
-    "quantize_kv", "update_quant_cache",
-    "QueryResult", "RankService", "RankServiceConfig",
-    "RankQueue", "QueueTicket", "CacheSpill", "PlanSpill",
-    "ServePipeline", "PipelineJob",
-    "BACKENDS", "SweepBackend", "SweepBatch", "DenseSweepBackend",
-    "ShardedSweepBackend", "BsrSweepBackend", "make_backend",
-    "select_backend", "shared_mesh",
-    "SweepPlan", "DensePlan", "ShardedPlan", "BsrPlan", "PlanCache",
-    "structure_key",
-    "MetricsRegistry", "StatsServer", "Counter", "Gauge", "Histogram",
-]
+_EXPORTS = {
+    "backends": ("BACKENDS", "BsrSweepBackend", "DenseSweepBackend",
+                 "ShardedSweepBackend", "SweepBackend", "SweepBatch",
+                 "make_backend", "select_backend", "shared_mesh"),
+    "kvquant": ("dequantize_kv", "init_quant_cache", "quant_decode_attention",
+                "quantize_kv", "update_quant_cache"),
+    "pipeline": ("PipelineJob", "ServePipeline"),
+    "plans": ("BsrPlan", "DensePlan", "PlanCache", "ShardedPlan", "SweepPlan",
+              "structure_key"),
+    "queue": ("QueueTicket", "RankQueue"),
+    "rank_service": ("QueryResult", "RankService", "RankServiceConfig"),
+    "spill": ("CacheSpill", "PlanSpill"),
+    "telemetry": ("Counter", "Gauge", "Histogram", "MetricsRegistry",
+                  "StatsServer"),
+}
+_MODULE_OF = {name: mod for mod, names in _EXPORTS.items() for name in names}
+
+__all__ = sorted(_MODULE_OF)
+
+
+def __getattr__(name):
+    mod = _MODULE_OF.get(name)
+    if mod is None:
+        raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+    value = getattr(importlib.import_module(f".{mod}", __name__), name)
+    globals()[name] = value
+    return value

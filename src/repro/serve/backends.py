@@ -66,6 +66,7 @@ from ..sparse.dist import (build_edge_shards_cols,
 from ..sparse.spmv import normalize_l1
 from .plans import (BsrPlan, DensePlan, ShardedPlan, SweepPlan,
                     structure_key)
+from .telemetry import span
 
 BACKENDS = ("dense", "sharded", "bsr")
 
@@ -327,8 +328,9 @@ def _converge_batch(h0, src, dst, w, ca, ch, mask, tol, max_iter,
     # finalize + certificate: one extra full-precision sweep from the
     # published h yields both the recomputed authority (same as
     # hits._finalize) and the residual bound ‖sweep(h) − h‖₁
-    h2, a = sweep(h)
-    res = jnp.sum(jnp.abs(h2 - h), axis=0)
+    with jax.named_scope("hits.certificate"):
+        h2, a = sweep(h)
+        res = jnp.sum(jnp.abs(h2 - h), axis=0)
     return h, normalize_l1(a, axis=0), conv, res
 
 
@@ -365,13 +367,18 @@ class DenseSweepBackend(SweepBackend):
 
     def sweep(self, plan: DensePlan, b: SweepBatch):
         self._check(plan, b)
-        h, a, conv, res = _converge_batch(
-            jnp.asarray(b.h0, b.dtype), plan.src, plan.dst, plan.w,
-            jnp.asarray(b.ca, b.dtype), jnp.asarray(b.ch, b.dtype),
-            jnp.asarray(b.mask, b.dtype), b.tol, b.max_iter,
-            rank_k=int(b.rank_k), stable_sweeps=int(b.stable_sweeps),
-            bulk_dtype=b.ladder_key() or None, bulk_tol=b.bulk_tol())
-        return np.asarray(h), np.asarray(a), np.asarray(conv), np.asarray(res)
+        with span("backend.upload"):
+            h0, ca, ch, mask = (jnp.asarray(x, b.dtype)
+                                for x in (b.h0, b.ca, b.ch, b.mask))
+        with span("backend.converge"):
+            # the readback below waits for these anyway: no extra sync
+            out = jax.block_until_ready(_converge_batch(
+                h0, plan.src, plan.dst, plan.w, ca, ch, mask, b.tol,
+                b.max_iter, rank_k=int(b.rank_k),
+                stable_sweeps=int(b.stable_sweeps),
+                bulk_dtype=b.ladder_key() or None, bulk_tol=b.bulk_tol()))
+        with span("backend.readback"):
+            return tuple(np.asarray(x) for x in out)
 
 
 # ----------------------------------------------------------------- sharded

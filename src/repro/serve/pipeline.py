@@ -64,6 +64,7 @@ from ..core.weights import accel_weights
 from ..graph.structure import next_pow2
 from ..graph.subgraph import root_set_key
 from .backends import SweepBatch
+from .telemetry import span
 
 
 @dataclasses.dataclass
@@ -385,12 +386,13 @@ class ServePipeline:
         return a is not None and b is not None and a[0] < b[1] and a[1] > b[0]
 
     def _traced(self, fn, arg, run_id: int, j: int, stage: str):
-        t0 = time.perf_counter()
+        sp = span(f"pipeline.{stage}", self._m_stage[stage], run=run_id,
+                  batch=j)
         try:
-            return fn(arg)
+            with sp:
+                return fn(arg)
         finally:
-            t1 = time.perf_counter()
-            self._m_stage[stage].observe((t1 - t0) * 1e3)
+            t0, t1 = sp.t0, sp.t1
             with self._meta_lock:
                 self.trace.append((run_id, j, stage, t0, t1))
                 # incremental overlap accounting: an overlap pair —
@@ -564,7 +566,8 @@ class ServePipeline:
                 if exc is None:
                     try:
                         self._traced(self.sweep, asm, run_id, j, "sweep")
-                        self._publish_barrier(st, j, depth, total)
+                        with span("pipeline.barrier", run=run_id, batch=j):
+                            self._publish_barrier(st, j, depth, total)
                         results = self._traced(self.publish, asm, run_id,
                                                j, "publish")
                     except BaseException as e:  # noqa: BLE001 — per job

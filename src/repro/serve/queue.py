@@ -68,6 +68,7 @@ import numpy as np
 
 from ..graph.subgraph import root_set_key
 from .pipeline import PipelineJob
+from .telemetry import span
 
 # per-class latency samples kept for percentile reporting (bounded so a
 # long-lived queue never grows without bound)
@@ -79,11 +80,14 @@ class QueueTicket:
     dispatches (or the queue rejects/sheds it)."""
 
     def __init__(self, key: str, priority: int = 0,
-                 deadline_at: float = math.inf):
+                 deadline_at: float = math.inf,
+                 submitted_at: Optional[float] = None):
         self.key = key
         self.priority = int(priority)
         self.deadline_at = float(deadline_at)  # perf_counter instant
-        self.submitted_at = time.perf_counter()
+        # entry to ``submit``: before any backpressure wait
+        self.submitted_at = (time.perf_counter() if submitted_at is None
+                             else submitted_at)
         self._done = threading.Event()
         self._result = None
         self._exc: Optional[BaseException] = None
@@ -164,7 +168,8 @@ class RankQueue:
             "deadline_miss": reg.counter("queue.deadline_miss"),
             "degraded": reg.counter("queue.degraded"),
         })
-        self._m_wait = reg.histogram("queue.wait_ms")  # submit -> dispatch
+        self._m_wait = reg.histogram("queue.wait_ms")  # admission -> dispatch
+        self._m_admit = reg.histogram("queue.admit_ms")  # submit -> admission
         reg.gauge("queue.pending")
         reg.counter("queue.drains")
         reg.counter("queue.undrains")
@@ -195,10 +200,18 @@ class RankQueue:
         resolves immediately with ``status="shed"`` (never blocks), and a
         guaranteed submit evicts the least-urgent sheddable column before
         falling back to blocking backpressure.
+
+        The ``queue.admit`` span, and ``queue.admit_ms``, time the call
+        from entry to admission, backpressure wait included; the ticket's
+        ``latency_s`` starts at the same entry.
         """
+        with span("queue.admit", self._m_admit, priority=int(priority)) as sp:
+            return self._admit(roots, int(priority), deadline_ms, sp.t0)
+
+    def _admit(self, roots, priority: int, deadline_ms: Optional[float],
+               entered: float) -> QueueTicket:
         roots_u = self.service.validate_roots(roots)
         key = root_set_key(roots_u)
-        priority = int(priority)
         deadline_at = (math.inf if deadline_ms is None
                        else time.perf_counter() + float(deadline_ms) / 1e3)
         with self._cond:
@@ -206,14 +219,14 @@ class RankQueue:
                 raise RuntimeError("queue is closed")
             self.stats["submitted"] += 1
             self._class(priority)["submitted"] += 1
-            t = self._coalesce(key, priority, deadline_at)
+            t = self._coalesce(key, priority, deadline_at, entered)
             if t is not None:  # one column serves all tickets for the key
                 return t
             while len(self._pending) >= self.max_pending and not self._closed:
                 if priority >= self.shed_priority:
                     # best-effort under overload: resolve as shed NOW
                     # rather than queue-blocking guaranteed traffic
-                    t = QueueTicket(key, priority, deadline_at)
+                    t = QueueTicket(key, priority, deadline_at, entered)
                     self._shed([t], roots_u)
                     return t
                 if self._evict_sheddable():
@@ -222,26 +235,26 @@ class RankQueue:
                 # the wait releases the lock: another thread may have queued
                 # this same key meanwhile — inserting a second _Pending
                 # would orphan that thread's tickets, so re-check
-                t = self._coalesce(key, priority, deadline_at)
+                t = self._coalesce(key, priority, deadline_at, entered)
                 if t is not None:
                     return t
             if self._closed:
                 raise RuntimeError("queue is closed")
-            t = QueueTicket(key, priority, deadline_at)
+            t = QueueTicket(key, priority, deadline_at, entered)
             self._pending[key] = _Pending(roots_u, [t], time.perf_counter(),
                                           priority, deadline_at)
             self._cond.notify_all()
             return t
 
-    def _coalesce(self, key: str, priority: int = 0,
-                  deadline_at: float = math.inf) -> Optional[QueueTicket]:
+    def _coalesce(self, key: str, priority: int, deadline_at: float,
+                  entered: float) -> Optional[QueueTicket]:
         """Under the lock: attach a ticket to ``key``'s pending column if
         one exists. The column inherits the most urgent class/deadline
         among its tickets (it serves all of them)."""
         p = self._pending.get(key)
         if p is None:
             return None
-        t = QueueTicket(key, priority, deadline_at)
+        t = QueueTicket(key, priority, deadline_at, entered)
         p.tickets.append(t)
         p.priority = min(p.priority, priority)
         if deadline_at < p.deadline_at:
@@ -509,10 +522,13 @@ class RankQueue:
 
         The pipeline pulls this generator from its prepare worker, so at
         depth >= 2 the wait itself runs while the previous batch sweeps
-        on the driving thread.
+        on the driving thread. The wait is the ``queue.flush_wait`` span;
+        its ``batch`` id is the job's index in the stream, the pipeline's
+        ``batch`` id of the same job.
         """
+        index = 0
         while True:
-            with self._cond:
+            with span("queue.flush_wait", batch=index), self._cond:
                 while True:
                     if self._pending:
                         n = len(self._pending)
@@ -555,6 +571,7 @@ class RankQueue:
                     self.stats[reason] += 1
                     backlog = len(self._pending)
                 yield self._job(batch, backlog=backlog)
+                index += 1
 
     def _loop(self):
         # drive the job stream through the service's staged pipeline;

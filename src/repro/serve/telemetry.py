@@ -32,6 +32,14 @@ renderers did not have to change while the registry became the single
 source of truth. ``LabeledView`` does the same for the one nested dict
 (``backend_batches``: label value -> count).
 
+``span`` is the one tracing primitive: a context manager that times a
+layer boundary into an optional ``Histogram`` and, while a
+``jax.profiler`` session is on, writes the same interval as a
+``TraceAnnotation`` on the profiler's host plane, the clock the device
+trace uses. Spans nest per thread, and a nested span inherits its
+enclosing span's ids (run, batch, job, sweep), so the spans of one batch
+or one job share them. ``docs/OPERATIONS.md`` lists every span.
+
 ``StatsServer`` is the ops endpoint: a stdlib ``ThreadingHTTPServer``
 serving ``GET /healthz`` (200 ``ok`` / 503 ``draining`` text) and ``GET
 /stats.json`` (the composed snapshot, numpy-safe JSON) on a loopback
@@ -42,12 +50,14 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 from collections import deque
 from collections.abc import MutableMapping
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 # default histogram reservoir size (recent-window percentiles); matches
 # the queue's pre-registry per-class latency window so reported p50/p95
@@ -236,6 +246,49 @@ class MetricsRegistry:
                 out[name] = {lbl: _render(kind, m)
                              for lbl, m in sorted(ms.items())}
         return out
+
+
+_span_ids = threading.local()  # .ids: the innermost open span's ids
+_tracing = TraceAnnotation.is_enabled
+_clock = time.perf_counter
+
+
+class span:
+    """``with span(name, hist, **ids):`` — one layer boundary.
+
+    Stamps ``t0``/``t1`` on ``time.perf_counter`` and, if ``hist`` is
+    given, observes the duration in ms, on success and on failure alike.
+    While a profiler session is on it also opens a ``TraceAnnotation``
+    named ``name`` whose arguments are the enclosing span's ids updated
+    with ``ids``; with no session it costs the annotation's enabled check
+    and two clock reads.
+    """
+
+    __slots__ = ("name", "hist", "ids", "t0", "t1", "_ann", "_outer")
+
+    def __init__(self, name: str, hist: Optional[Histogram] = None, **ids):
+        self.name, self.hist, self.ids = name, hist, ids
+        self._ann = None
+
+    def __enter__(self) -> "span":
+        if _tracing():
+            self._outer = getattr(_span_ids, "ids", None)
+            ids = {**self._outer, **self.ids} if self._outer else self.ids
+            _span_ids.ids = ids
+            self._ann = TraceAnnotation(self.name, **ids)
+            self._ann.__enter__()
+        self.t0 = _clock()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1 = _clock()
+        if self.hist is not None:
+            self.hist.observe((self.t1 - self.t0) * 1e3)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+            _span_ids.ids = self._outer
+        return False
 
 
 class LabeledView(MutableMapping):
