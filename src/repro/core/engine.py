@@ -4,7 +4,10 @@ bounded-staleness straggler tolerance, and elastic re-sharding.
 The engine partitions edges into ``n_shards`` virtual shards (on hardware,
 one per host/slice; here executed sequentially — the combine semantics are
 identical). Per sweep each shard contributes a partial authority/hub
-product; the combine is a sum, so the engine tolerates:
+product, summed per page with no scatter: each shard's edges are sorted
+once per build by destination and by source (``_order``), and each pass
+is a gather and a scan that restarts at each page's first edge
+(``_segment_sum``). The combine is a sum, so the engine tolerates:
 
 * **Stragglers**: a shard that misses the deadline reuses its previous
   partial (bounded staleness ``stale_limit``). Power iteration is a
@@ -33,20 +36,62 @@ from ..serve.telemetry import span
 from .weights import accel_weights
 
 
-@partial(jax.jit, static_argnames=("n",))
-def _partial_a(h_scaled, src, dst, w, n):
-    with jax.named_scope("segsum.gather"):
-        x = jnp.take(h_scaled, src) * w
-    with jax.named_scope("segsum.scatter"):
-        return jax.ops.segment_sum(x, dst, num_segments=n)
+_LANES = 128  # the sorted edges lie in the columns of a (rows, 128) array
 
 
 @partial(jax.jit, static_argnames=("n",))
-def _partial_h(a_scaled, src, dst, w, n):
+def _order(key, other, w, n):
+    """One shard's edges in ``key`` order, as ``_segment_sum`` takes them.
+
+    Column c of each (rows, 128) array holds the sorted edges
+    ``[c * rows, (c + 1) * rows)``: each edge's other endpoint, its weight
+    and whether it opens its key's run; the slots past the last edge hold
+    weight 0 and extend the last run. Per page: the flat index of its last
+    edge in that layout, and whether it has an edge here.
+    """
+    e = key.shape[0]
+    sk, perm = jax.lax.sort((key, jnp.arange(e, dtype=jnp.int32)),
+                            num_keys=1)
+    rows = -(-e // (8 * _LANES)) * 8
+    pad = rows * _LANES - e
+
+    def columns(x):
+        return jnp.pad(x, (0, pad)).reshape(_LANES, rows).T
+
+    start = jnp.concatenate([jnp.ones((1,), bool), sk[1:] != sk[:-1]])
+    # one past each page's last edge, 0 where it has none: an integer max,
+    # exact in any order
+    end = jnp.zeros((n,), jnp.int32).at[sk].max(
+        jnp.arange(1, e + 1, dtype=jnp.int32), indices_are_sorted=True)
+    last = jnp.maximum(end - 1, 0)
+    return (columns(jnp.take(other, perm)), columns(jnp.take(w, perm)),
+            columns(start), last % rows * _LANES + last // rows, end > 0)
+
+
+def _restart(a, b):
+    """Combine of an inclusive scan that restarts at each flagged element."""
+    fa, va = a
+    fb, vb = b
+    return fa | fb, jnp.where(fb, vb, va + vb)
+
+
+@jax.jit
+def _segment_sum(v, other, w, start, last, has):
+    """``out[j] = sum(v[other[e]] * w[e] for the edges e keyed j)`` over one
+    shard's edges in key order (``_order``), with no scatter: a scan that
+    restarts at each key's first edge adds each page's products in the
+    vector's dtype, and each page reads its last edge's running sum."""
     with jax.named_scope("segsum.gather"):
-        x = jnp.take(a_scaled, dst) * w
-    with jax.named_scope("segsum.scatter"):
-        return jax.ops.segment_sum(x, src, num_segments=n)
+        x = jnp.take(v, other) * w
+    with jax.named_scope("segsum.scan"):
+        # down each column, then a run that crosses into a column takes
+        # the sum carried out of the columns before it
+        opened, run = jax.lax.associative_scan(_restart, (start, x), axis=0)
+        _, out = jax.lax.associative_scan(_restart, (opened[-1], run[-1]))
+        carry = jnp.concatenate([jnp.zeros((1,), out.dtype), out[:-1]])
+        run = run + jnp.where(opened, 0, carry)
+    with jax.named_scope("segsum.ends"):
+        return jnp.where(has, jnp.take(run.reshape(-1), last), 0)
 
 
 @dataclasses.dataclass
@@ -89,12 +134,17 @@ class RankingEngine:
             # the host side of the transfers; their device side is not
             # waited for here
             with span("engine.upload"):
-                self.shards = [(jnp.asarray(src), jnp.asarray(dst),
-                                jnp.asarray(w, dtype))
-                               for src, dst, w in host]
+                dev = [(jnp.asarray(src), jnp.asarray(dst),
+                        jnp.asarray(w, dtype)) for src, dst, w in host]
                 self.ca, self.ch = ((None, None) if weights is None else
                                     (jnp.asarray(weights[0], dtype),
                                      jnp.asarray(weights[1], dtype)))
+            # each shard's edges by destination (the authority pass) and by
+            # source (the hub pass), sorted on the device
+            with span("engine.order"):
+                self.shards = [(_order(dst, src, w, self.n),
+                                _order(src, dst, w, self.n))
+                               for src, dst, w in dev]
 
     # ------------------------------------------------------------- internals
     def _sweep(self, h, cache_a, cache_h, staleness, force_fresh=False):
@@ -103,7 +153,7 @@ class RankingEngine:
         prob = 0.0 if force_fresh else self.straggler_prob
         hs = h if self.ch is None else h * self.ch
         partials_a = []
-        for s, (src, dst, w) in enumerate(self.shards):
+        for s, (by_dst, _) in enumerate(self.shards):
             straggles = (self.rng.random() < prob
                          and staleness[s] < self.stale_limit
                          and cache_a[s] is not None)
@@ -112,14 +162,14 @@ class RankingEngine:
                 staleness[s] += 1
                 stale_events += 1
             else:
-                p = _partial_a(hs, src, dst, w, self.n)
+                p = _segment_sum(hs, *by_dst)
                 partials_a.append(p)
                 cache_a[s] = p
                 staleness[s] = 0
         a = sum(partials_a)
         as_ = a if self.ca is None else a * self.ca
         partials_h = []
-        for s, (src, dst, w) in enumerate(self.shards):
+        for s, (_, by_src) in enumerate(self.shards):
             straggles = (self.rng.random() < prob
                          and staleness[s] < self.stale_limit
                          and cache_h[s] is not None)
@@ -128,7 +178,7 @@ class RankingEngine:
                 staleness[s] += 1
                 stale_events += 1
             else:
-                p = _partial_h(as_, src, dst, w, self.n)
+                p = _segment_sum(as_, *by_src)
                 partials_h.append(p)
                 cache_h[s] = p
         h_new = sum(partials_h)

@@ -92,7 +92,7 @@ def test_engine_spans_nest_with_job_and_sweep_ids(traced):
         by.setdefault(s[0], []).append(s)
     (build,) = by["engine.build"]
     job = build[4]["job"]
-    for name in ("engine.partition", "engine.upload"):
+    for name in ("engine.partition", "engine.upload", "engine.order"):
         (inner,) = by[name]
         assert _inside(inner, build) and inner[4] == {"job": job}
     sweeps, syncs = by["engine.sweep"], by["engine.sync"]

@@ -1,5 +1,5 @@
-"""Compile the serve path's device programs for a described TPU v5e, with
-no chip attached.
+"""Compile the serve path's device programs, and the whole-graph engine's
+pass, for a described TPU v5e, with no chip attached.
 
 The TPU compiler is installed with libtpu, and it compiles for a topology
 that is described rather than attached, so these tests catch what the
@@ -14,6 +14,7 @@ The topology is described inside a fixture, never at import: only one
 process may load libtpu at a time, and every test worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -120,6 +121,26 @@ def test_seg_matmul_compiles_under_x64(one_chip):
         s((e, 1), jnp.int32), s((e, 1), jnp.int32), 32, bs=BS,
         interpret=False).compile()
     _compiled_ok(compiled, kernel=True)
+
+
+# ------------------------------------------------------ whole-graph engine
+
+
+@pytest.mark.parametrize("dtype", ["float64", "float32"])
+def test_engine_segment_sum_compiles_without_scatter(one_chip, dtype):
+    """One shard's pass of the whole-graph engine at the stanford
+    back-button graph's size (225,441 pages, 4,303,868 edges in 8 shards),
+    in the engine's dtype and the control's: a gather and a scan, with no
+    scatter left in the program."""
+    from repro.core import engine
+    s = lambda shape, dt: _shape(one_chip, shape, dt)  # noqa: E731
+    n, rows = 225441, 4208  # 537,984 edges in 128 columns
+    dt, i32 = jnp.dtype(dtype), jnp.int32
+    compiled = engine._segment_sum.lower(
+        s((n,), dt), s((rows, 128), i32), s((rows, 128), dt),
+        s((rows, 128), jnp.bool_), s((n,), i32), s((n,), jnp.bool_)).compile()
+    _compiled_ok(compiled, kernel=False)
+    assert not re.search(r"\bscatter\(", compiled.as_text())  # an HLO op
 
 
 # ------------------------------------------------------- dense and sharded
