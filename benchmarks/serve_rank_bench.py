@@ -59,6 +59,7 @@ import numpy as np  # noqa: E402
 
 from repro.core import accel_hits  # noqa: E402
 from repro.graph import Graph, WebGraphSpec, generate_webgraph  # noqa: E402
+from repro.launch.serve_rank import roll_delta  # noqa: E402
 from repro.serve import RankService, RankServiceConfig  # noqa: E402
 
 
@@ -399,10 +400,11 @@ def stats_endpoint_axis(g, cfg, queries, deadline_ms):
 def delta_swap_axis(g, cfg, queries, deadline_ms):
     """Zero-downtime edge-delta roll (ISSUE 9; armed in --smoke).
 
-    Live guaranteed traffic through the queue, then the operator roll:
-    drain -> ``apply_edge_delta`` (a reweight inside query 0's union, so
-    the delta provably changes what that query serves) -> undrain ->
-    resubmit the whole stream. Gates: zero guaranteed-class sheds across
+    Live guaranteed traffic through the queue, then the operator roll
+    with admission open (``launch.serve_rank.roll_delta``: a reweight
+    inside query 0's union, so the delta provably changes what that
+    query serves), then the whole stream resubmitted. Gates: zero
+    guaranteed-class sheds across
     the roll, at least one plan *patched* in place with
     ``service.plan.misses`` unmoved (weight-only deltas must not rebuild
     surviving layouts), and every post-delta result <= 1e-10 L1 of a
@@ -417,11 +419,8 @@ def delta_swap_axis(g, cfg, queries, deadline_ms):
         u = int(fs.nodes[fs.graph.src[0]])
         v = int(fs.nodes[fs.graph.dst[0]])
         misses_before = svc.stats["plan_misses"]
-        t0 = time.perf_counter()
-        rq.drain(flush_spill=False)
-        summ = svc.apply_edge_delta(reweights=[(u, v, 2.0)])
-        rq.undrain()
-        roll_ms = (time.perf_counter() - t0) * 1e3
+        summ = roll_delta(svc, {"reweights": [(u, v, 2.0)]})
+        roll_ms = summ["roll_ms"]
         post = [t.result(timeout=600)
                 for t in [rq.submit(q) for q in queries]]
         stats = rq.snapshot_stats()
@@ -735,7 +734,7 @@ def main():
           f"submitted={ep_snap['queue']['queue.submitted']} "
           f"batches={ep_snap['queue']['queue.batches']}")
 
-    # --- delta-swap axis: a zero-downtime drain -> swap -> undrain roll
+    # --- delta-swap axis: a roll with admission open
     # under live guaranteed traffic (ISSUE 9; armed in --smoke)
     ds = delta_swap_axis(g, cfg, queries, args.deadline_ms)
     print(f"serve/delta_swap,0,patched={ds['patched']} built={ds['built']} "
@@ -890,7 +889,7 @@ def main():
     # ISSUE 9: a weight-only delta rolled under live traffic must serve
     # post-delta-correct results (<= 1e-10 vs a cold-built service)
     # without rebuilding surviving plans and without shedding a single
-    # guaranteed-class request across the drain -> undrain gap
+    # guaranteed-class request across the roll
     ok_delta = (ds["l1"] <= 1e-10 and ds["patched"] >= 1
                 and ds["built"] == 0 and ds["shed0"] == 0)
     print(f"ACCEPTANCE delta_swap: {'PASS' if ok_delta else 'FAIL'} "

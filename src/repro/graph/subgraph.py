@@ -10,8 +10,11 @@ module does it structurally.
 Expansion reads the padded neighbor tables of ``graph.structure``
 (the same ``padded_neighbors`` the sampler builds on, over the forward and
 reversed graph), so the caps are the same degree-truncation the sampler
-applies. Everything is host-side numpy — extraction is preprocessing, like
-the rest of ``graph.structure``.
+applies. Neighbors are listed in page-id order, so where a page has more
+than the cap, the ones with the smallest ids are kept: a base set depends
+on the graph's links alone, not on the order of its edge list (a live
+delta appends its links at the end). Everything is host-side numpy —
+extraction is preprocessing, like the rest of ``graph.structure``.
 """
 from __future__ import annotations
 
@@ -57,6 +60,12 @@ class SubgraphExtractor:
     """
 
     def __init__(self, g: Graph, out_cap: int = 32, in_cap: int = 32):
+        # edges by (src, dst): out-lists by dst and, through the reversed
+        # graph's stable sort, in-lists by src (timsort: near-linear on an
+        # edge list that is sorted, or sorted but for appended links)
+        order = np.argsort(g.src.astype(np.int64) * g.n_nodes + g.dst,
+                           kind="stable")
+        g = Graph(g.n_nodes, g.src[order], g.dst[order])
         self.g = g
         self.out_cap = out_cap
         self.in_cap = in_cap

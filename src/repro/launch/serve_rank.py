@@ -16,9 +16,9 @@ loopback for probes and scrapers; in queued mode SIGTERM/SIGINT triggers
 a graceful drain — admission stops, pending best-effort requests resolve
 as shed, guaranteed pending requests are served, the spill is flushed and
 generation-GC'd (`--spill-keep-generations`), and the process exits 0;
-SIGHUP (with `--delta-file`) rolls an edge changeset in without a
-restart — drain, `apply_edge_delta`, undrain — so guaranteed traffic
-never drops across a graph mutation.
+SIGHUP (with `--delta-file`) rolls a changeset of links and new pages in
+without a restart and without closing admission (`roll_delta`), and
+prints the graph version it acknowledged.
 
   PYTHONPATH=src python -m repro.launch.serve_rank --dataset wikipedia \
       --scale 0.5 --requests 200 --v 8
@@ -41,42 +41,36 @@ jax.config.update("jax_enable_x64", True)
 import numpy as np  # noqa: E402
 
 
+DELTA_KEYS = ("adds", "removes", "reweights", "pages")
+
+
 def load_delta_file(path: str) -> dict:
-    """Parse a JSON edge-changeset spec: ``{"adds": [[s, d, w?], ...],
-    "removes": [[s, d], ...], "reweights": [[s, d, w], ...]}`` (all keys
-    optional). Validation of ids/weights happens in ``apply_edge_delta``."""
+    """Parse a JSON changeset spec: ``{"adds": [[s, d, w?], ...],
+    "removes": [[s, d], ...], "reweights": [[s, d, w], ...], "pages": k}``
+    (all keys optional; ``pages`` new pages take the ids after the
+    graph's last, and the rows may name them). Validation of ids/weights
+    happens in ``apply_edge_delta``."""
     import json
     with open(path) as f:
         spec = json.load(f)
-    unknown = set(spec) - {"adds", "removes", "reweights"}
+    unknown = set(spec) - set(DELTA_KEYS)
     if unknown:
         raise ValueError(f"delta file {path}: unknown keys "
                          f"{sorted(unknown)}")
-    return {k: spec.get(k) for k in ("adds", "removes", "reweights")}
+    return {k: spec.get(k) for k in DELTA_KEYS}
 
 
-def roll_delta(svc, q, delta: dict, draining=None):
-    """Zero-downtime edge-delta roll: drain -> swap -> undrain.
-
-    Stops admission and serves every guaranteed pending request
-    (``q.drain`` — best-effort pending resolves as shed, nothing
-    guaranteed is dropped), applies the edge changeset while the service
-    is quiescent, then re-opens admission (``q.undrain``). ``draining``
-    (an optional threading.Event) is held set for the duration so
-    ``/healthz`` reports the roll. Returns (drain_summary,
-    delta_summary)."""
-    if draining is not None:
-        draining.set()
-    try:
-        d = q.drain(flush_spill=True)
-        s = svc.apply_edge_delta(adds=delta.get("adds"),
-                                 removes=delta.get("removes"),
-                                 reweights=delta.get("reweights"))
-        q.undrain()
-    finally:
-        if draining is not None:
-            draining.clear()
-    return d, s
+def roll_delta(svc, delta: dict) -> dict:
+    """Roll a changeset (``load_delta_file``'s keys) into the running
+    service: the next graph version is built beside the live one and
+    swapped in, while queued traffic keeps being admitted and served
+    (``RankService.apply_edge_delta``). Returns its acknowledgement, whose
+    ``version`` every request submitted from then on is answered at, or
+    later."""
+    return svc.apply_edge_delta(adds=delta.get("adds"),
+                                removes=delta.get("removes"),
+                                reweights=delta.get("reweights"),
+                                pages=delta.get("pages") or 0)
 
 
 def zipf_query_stream(rng, n_nodes: int, n_queries: int, roots_per_query: int,
@@ -186,10 +180,11 @@ def build_parser() -> argparse.ArgumentParser:
                     help="spill GC: newest step_* generations kept per "
                          "entry stream (compacted at init and on drain)")
     ap.add_argument("--delta-file", default=None,
-                    help="JSON edge changeset ({adds: [[s,d,w?]..], "
-                         "removes: [[s,d]..], reweights: [[s,d,w]..]}); "
-                         "queued frontend applies it on SIGHUP via a "
-                         "zero-downtime drain -> swap -> undrain roll")
+                    help="JSON changeset ({adds: [[s,d,w?]..], "
+                         "removes: [[s,d]..], reweights: [[s,d,w]..], "
+                         "pages: k}); the queued frontend rolls it in on "
+                         "SIGHUP with admission open and prints the "
+                         "acknowledged graph version")
     ap.add_argument("--stats-port", type=int,
                     default=(CONFIG.serve_stats_port
                              if CONFIG.serve_stats_port >= 0 else None),
@@ -282,13 +277,24 @@ def main():
         stop = threading.Event()
         for sig in (signal.SIGTERM, signal.SIGINT):
             signal.signal(sig, lambda *_: stop.set())
-        # SIGHUP rolls the --delta-file changeset in without a restart:
-        # drain -> apply_edge_delta -> undrain (docs/OPERATIONS.md)
+        # SIGHUP rolls the --delta-file changeset in without a restart,
+        # on a thread of its own, while requests keep being submitted
+        # (docs/OPERATIONS.md)
         roll = threading.Event()
         delta_spec = (load_delta_file(args.delta_file)
                       if args.delta_file else None)
+        roller = None
         if delta_spec is not None and hasattr(signal, "SIGHUP"):
             signal.signal(signal.SIGHUP, lambda *_: roll.set())
+
+            def _roll():
+                ds = roll_delta(svc, delta_spec)
+                print(f"delta roll: version {ds['version']} acknowledged, "
+                      f"{ds['invalidated']} cache entries invalidated, "
+                      f"structural={ds['structural']}, {ds['pages']} "
+                      f"pages added, roll {ds['roll_ms']:.1f}ms "
+                      f"(swap {ds['swap_ms']:.1f}ms), admission stayed "
+                      f"open", flush=True)
         # one request at a time through the micro-batching queue, Poisson
         # inter-arrivals — the live-traffic regime the sync path can't see
         gaps = (rng.exponential(1.0 / args.arrival_qps, len(stream))
@@ -303,15 +309,12 @@ def main():
             for roots, gap in zip(stream, gaps):
                 if stop.is_set():
                     break
-                if roll.is_set():
+                if roll.is_set() and (roller is None
+                                      or not roller.is_alive()):
                     roll.clear()
-                    d, ds = roll_delta(svc, q, delta_spec, draining)
-                    print(f"delta roll: drained ({d['served']} served, "
-                          f"{d['shed']} best-effort shed), "
-                          f"{ds['invalidated']} cache entries invalidated, "
-                          f"structural={ds['structural']}, swap "
-                          f"{ds['swap_ms']:.1f}ms, admission re-opened",
-                          flush=True)
+                    roller = threading.Thread(target=_roll,
+                                              name="delta-roll")
+                    roller.start()
                 if gap:
                     time.sleep(gap)
                 pri = (args.shed_priority
@@ -332,6 +335,8 @@ def main():
                     f"(gc removed {d['gc_removed']})")
                 print(drain_line, flush=True)
             results = [t.result(timeout=600) for t in tickets]
+            if roller is not None:
+                roller.join()
         dt = time.time() - t0
         lat = np.array([t.latency_s for t in tickets]) * 1e3
         qs = q.snapshot_stats()

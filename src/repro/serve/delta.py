@@ -1,29 +1,34 @@
 """Edge-delta classification and application for live graph mutation.
 
-``RankService.apply_edge_delta`` takes an operator's edge changeset —
-adds, removes, reweights — and rolls it into a running service without a
-restart. This module owns the graph-side half of that: normalizing and
-validating the changeset, classifying it (weight-only vs structural),
-and producing the post-delta edge list + edge-weight table. The
-service-side half (cache invalidation, plan patch-vs-replan, spill
-generation bump, warm-table carryover) lives in ``rank_service.py``.
+``RankService.apply_edge_delta`` takes an operator's changeset — link
+adds, removes, reweights, and new pages — and rolls it into a running
+service without a restart and without closing admission. This module
+owns the graph-side half of that: normalizing and validating the
+changeset, classifying it (weight-only vs structural), and producing the
+post-delta edge list + edge-weight table. The service-side half (the
+version swap, cache invalidation, plan patch-vs-replan, spill generation
+bump, warm-table carryover) lives in ``rank_service.py``.
 
 Classification drives how much cached state survives:
 
-* **weight-only** (reweights, no adds/removes) — every union subgraph
-  keeps its topology, so every cached plan's *layout* survives; backends
-  patch edge-value arrays / BSR block values in place
+* **weight-only** (reweights, no adds/removes/pages) — every union
+  subgraph keeps its topology, so every cached plan's *layout* survives;
+  backends patch edge-value arrays / BSR block values in place
   (``SweepBackend.patch``, probed lazily at the next plan lookup via the
   weight-blind ``plans.topology_key``).
-* **structural** (any add or remove) — the service's extractor rebuilds,
-  but plans are content-keyed: union subgraphs the delta doesn't touch
-  produce byte-identical padded edge arrays, so their plans (and cached
-  vectors outside the touched node set) keep hitting. Only affected
-  plans rebuild.
+* **structural** (any add, remove or new page) — the service builds a
+  new extractor, but plans are content-keyed: union subgraphs the delta
+  doesn't touch produce byte-identical padded edge arrays, so their plans
+  (and cached vectors outside the touched node set) keep hitting. Only
+  affected plans rebuild.
 
 In both cases the warm table carries over: the paper's premise is that
 pre-delta fixed points are excellent warm starts, so post-delta
 refreshes converge in a handful of sweeps instead of from uniform.
+
+**New pages.** A delta with ``pages=k`` grows an ``n``-page graph to
+``n + k`` pages with ids ``n, n+1, …, n+k-1``; its links may name them.
+A page with no links is ranked like any other dangling page.
 
 Weight rules: weights must be finite and nonzero. A reweight to 0 is a
 remove (and a zero-weight add is just a remove of nothing) — routing
@@ -81,13 +86,12 @@ def _pairs(spec, n_nodes: int, what: str, with_w: bool,
 
 @dataclasses.dataclass(frozen=True)
 class EdgeDelta:
-    """A normalized edge changeset against an n_nodes-node graph.
+    """A normalized changeset against an n_nodes-page graph.
 
     ``adds``/``removes``/``reweights`` are (k, 2) int64 (src, dst) pair
-    arrays; ``add_w``/``rw_w`` the aligned weights. Node ids are already
-    range-checked; weights finite and nonzero. Deltas change *edges*
-    only — the node-id space is fixed at service construction (warm
-    tables, caches, and spilled vectors are all indexed by it).
+    arrays; ``add_w``/``rw_w`` the aligned weights; ``pages`` the number
+    of new pages, whose ids follow the graph's last. Page ids are already
+    range-checked against ``n_nodes + pages``; weights finite and nonzero.
     """
 
     adds: np.ndarray
@@ -95,27 +99,32 @@ class EdgeDelta:
     removes: np.ndarray
     reweights: np.ndarray
     rw_w: np.ndarray
+    pages: int = 0
 
     @staticmethod
     def normalize(adds: Optional[Iterable] = None,
                   removes: Optional[Iterable] = None,
                   reweights: Optional[Iterable] = None,
-                  n_nodes: int = 0) -> "EdgeDelta":
-        a, aw = _pairs(adds, n_nodes, "adds", with_w=True)
-        r, _ = _pairs(removes, n_nodes, "removes", with_w=False)
-        rw, rww = _pairs(reweights, n_nodes, "reweights", with_w=True,
+                  n_nodes: int = 0, pages: int = 0) -> "EdgeDelta":
+        if int(pages) != pages or pages < 0:
+            raise ValueError(f"pages: want a whole number >= 0, got "
+                             f"{pages!r}")
+        n = n_nodes + int(pages)
+        a, aw = _pairs(adds, n, "adds", with_w=True)
+        r, _ = _pairs(removes, n, "removes", with_w=False)
+        rw, rww = _pairs(reweights, n, "reweights", with_w=True,
                          require_w=True)
-        return EdgeDelta(a, aw, r, rw, rww)
+        return EdgeDelta(a, aw, r, rw, rww, int(pages))
 
     @property
     def empty(self) -> bool:
         return not (len(self.adds) or len(self.removes)
-                    or len(self.reweights))
+                    or len(self.reweights) or self.pages)
 
     @property
     def structural(self) -> bool:
         """Does the delta change topology (vs edge values only)?"""
-        return bool(len(self.adds) or len(self.removes))
+        return bool(len(self.adds) or len(self.removes) or self.pages)
 
     def touched_nodes(self) -> np.ndarray:
         """Sorted unique endpoints of every changed edge — the node set
@@ -126,13 +135,26 @@ class EdgeDelta:
              self.reweights.ravel()]))
 
 
-def _table_of(g: Graph, table: Optional[EdgeTable]) -> EdgeTable:
-    """The service's current weight table, materialized (all-1.0 when no
-    delta has ever run)."""
-    if table is not None:
-        return table
-    keys = np.unique(g.src.astype(np.int64) * g.n_nodes + g.dst)
-    return keys, np.ones(len(keys), np.float64)
+def _table_of(g: Graph, table: Optional[EdgeTable], n: int) -> EdgeTable:
+    """A copy of the service's weight table keyed over ``n`` pages
+    (all-1.0 when no delta has ever run)."""
+    if table is None:
+        keys = np.unique(g.src.astype(np.int64) * n + g.dst)
+        return keys, np.ones(len(keys), np.float64)
+    keys, vals = table
+    if n != g.n_nodes:  # new pages: re-key (a new array); order is kept
+        return keys // g.n_nodes * n + keys % g.n_nodes, vals.copy()
+    return keys.copy(), vals.copy()
+
+
+def _member(keys: np.ndarray, q: np.ndarray) -> Tuple[np.ndarray,
+                                                     np.ndarray]:
+    """(positions, found) of the keys ``q`` in the sorted ``keys``: a
+    binary search each, so a changeset costs O(k log E), not a sort of
+    the table."""
+    pos = np.minimum(np.searchsorted(keys, q), max(len(keys) - 1, 0))
+    found = keys[pos] == q if len(keys) else np.zeros(len(q), bool)
+    return pos, found
 
 
 def apply_to_graph(g: Graph, table: Optional[EdgeTable],
@@ -146,48 +168,46 @@ def apply_to_graph(g: Graph, table: Optional[EdgeTable],
     removes/reweights of absent pairs and adds handled per the module
     rules above.
     """
-    n = g.n_nodes
+    n = g.n_nodes + delta.pages
     src = np.asarray(g.src)
     dst = np.asarray(g.dst)
-    gkeys = src.astype(np.int64) * n + dst
-    tkeys, tvals = _table_of(g, table)
-    tkeys, tvals = tkeys.copy(), tvals.copy()
+    tkeys, tvals = _table_of(g, table, n)
 
     if len(delta.removes):
         rk = np.unique(delta.removes[:, 0] * n + delta.removes[:, 1])
-        missing = rk[~np.isin(rk, tkeys)]
-        if missing.size:
+        pos, found = _member(tkeys, rk)
+        if not found.all():
+            missing = rk[~found]
             raise ValueError(
                 f"removes: {missing.size} pair(s) not in the graph "
                 f"(first: ({missing[0] // n}, {missing[0] % n}))")
-        keep = ~np.isin(gkeys, rk)
-        src, dst, gkeys = src[keep], dst[keep], gkeys[keep]
-        keep_t = ~np.isin(tkeys, rk)
-        tkeys, tvals = tkeys[keep_t], tvals[keep_t]
+        keep = ~np.isin(src.astype(np.int64) * n + dst, rk)
+        src, dst = src[keep], dst[keep]
+        tkeys, tvals = np.delete(tkeys, pos), np.delete(tvals, pos)
 
     if len(delta.adds):
         ak = delta.adds[:, 0] * n + delta.adds[:, 1]
         # last occurrence wins within one changeset
         ak, last = np.unique(ak[::-1], return_index=True)
         aw = delta.add_w[::-1][last]
-        exists = np.isin(ak, tkeys)
+        pos, exists = _member(tkeys, ak)
         # adding an existing pair == reweighting it (idempotent rolls)
-        pos = np.searchsorted(tkeys, ak[exists])
-        tvals[pos] = aw[exists]
+        tvals[pos[exists]] = aw[exists]
         new_k, new_w = ak[~exists], aw[~exists]
         if new_k.size:
             src = np.concatenate([src, (new_k // n).astype(src.dtype)])
             dst = np.concatenate([dst, (new_k % n).astype(dst.dtype)])
-            tkeys = np.concatenate([tkeys, new_k])
-            tvals = np.concatenate([tvals, new_w])
-            order = np.argsort(tkeys)
-            tkeys, tvals = tkeys[order], tvals[order]
+            # new_k is sorted: inserting at its search positions keeps
+            # the table sorted
+            at = np.searchsorted(tkeys, new_k)
+            tkeys = np.insert(tkeys, at, new_k)
+            tvals = np.insert(tvals, at, new_w)
 
     if len(delta.reweights):
         wk = delta.reweights[:, 0] * n + delta.reweights[:, 1]
-        pos = np.minimum(np.searchsorted(tkeys, wk), max(len(tkeys) - 1, 0))
-        bad = wk[tkeys[pos] != wk] if len(tkeys) else wk
-        if bad.size:
+        pos, found = _member(tkeys, wk)
+        if not found.all():
+            bad = wk[~found]
             raise ValueError(
                 f"reweights: {bad.size} pair(s) not in the graph "
                 f"(first: ({bad[0] // n}, {bad[0] % n}))")

@@ -11,14 +11,15 @@ the sweep long exactly when that idle time is most expensive).
 decomposes into four stages:
 
 * ``assemble`` — root-set cache probe, in-batch dedup, union-subgraph
-  extraction, padding, per-column induced weights and start vectors.
-  Pure host work.
+  extraction, padding, per-column induced weights and start vectors, all
+  on one graph version read once with the probe. Pure host work.
 * ``plan``     — ``PlanCache`` lookup (spill restore / build on miss) of
   the backend's structural layout. Host + transfer work.
 * ``sweep``    — the device convergence loop via the ``SweepBackend``.
 * ``publish``  — cache insert, spill write, warm-table update, result
   construction, stats, and frontend completion (``job.on_done``, e.g.
-  queue-ticket resolution).
+  queue-ticket resolution). Results carry the batch's graph version; a
+  batch whose version a roll has since replaced is not cached.
 
 ``run(jobs)`` executes a job stream through those stages. With
 ``depth == 1`` everything runs inline on the caller's thread — the exact
@@ -64,6 +65,7 @@ from ..core.weights import accel_weights
 from ..graph.structure import next_pow2
 from ..graph.subgraph import root_set_key
 from .backends import SweepBatch
+from .delta import lookup_weights
 from .telemetry import span
 
 
@@ -99,6 +101,7 @@ class _Assembled:
     statuses: list                 # per-todo "warm" | "cold"
     locs: list                     # per-todo union-local index arrays
     backend: Any = None
+    version: int = 0               # the graph version it is assembled on
     union: tuple = (0, 0)          # union subgraph (nodes, edges), unpadded
     batch: Optional[SweepBatch] = None
     lump: Any = None               # LumpMap when the batch is lump-reduced
@@ -160,7 +163,10 @@ class ServePipeline:
         """Host half #1: cache probe + dedup + union extraction + padding.
 
         State reads (vector cache, warm table) happen under the service
-        lock; the expensive extraction runs outside it.
+        lock; the expensive extraction runs outside it. The graph version
+        is read once, with the cache probe, and everything after reads
+        that snapshot: no batch mixes two versions, and its cache hits
+        are exact for it (a roll invalidates what it touches at the swap).
         """
         from .rank_service import QueryResult
 
@@ -175,6 +181,7 @@ class ServePipeline:
         # job's failure must not leave phantom served-work stats.
         probes = []      # [slot, roots, key, entry|None]
         with svc._lock:
+            snap = svc._live
             for slot, roots_u in enumerate(queries):
                 key = root_set_key(roots_u)
                 probes.append([slot, roots_u, key,
@@ -194,7 +201,9 @@ class ServePipeline:
                 svc._m_spill_read.observe((time.perf_counter() - t0) * 1e3)
             with svc._lock:
                 for k, plist in by_key.items():
-                    if disk[k] is None:
+                    # after a roll since the probe the read may predate
+                    # the spill's generation bump: not admitted
+                    if disk[k] is None or svc._live is not snap:
                         continue
                     e = svc._admit_spilled(k, disk[k])
                     for p in plist:
@@ -208,7 +217,8 @@ class ServePipeline:
                         roots=roots_u, nodes=entry.nodes,
                         authority=entry.authority, hub=entry.hub,
                         iters=0, status="hit", key=key,
-                        residual=entry.residual)
+                        residual=entry.residual,
+                        graph_version=snap.version)
                     continue
                 if key in dup_of:
                     asm.dups.append((slot, dup_of[key]))
@@ -216,14 +226,15 @@ class ServePipeline:
                 dup_of[key] = slot
                 misses.append((slot, roots_u, entry))
         svc._drain_spill()  # readmission may have queued evictee writes
+        asm.version = snap.version
         if not misses:
             return asm  # all hits: nothing to plan/sweep
 
         # the expensive host half — subgraph extraction — off the lock
         for slot, roots_u, entry in misses:
-            asm.todo.append((slot, svc.extractor.extract(roots_u), entry))
+            asm.todo.append((slot, snap.extractor.extract(roots_u), entry))
         subs = [t[1] for t in asm.todo]
-        union = svc.extractor.extract_union(subs)
+        union = snap.extractor.extract_union(subs)
         nodes_u = union.nodes
         n_u, e_u = len(nodes_u), union.graph.n_edges
         asm.union = (n_u, e_u)
@@ -236,10 +247,11 @@ class ServePipeline:
         w = np.zeros(e_pad)
         src[:e_u] = union.graph.src
         dst[:e_u] = union.graph.dst
-        # service-held per-pair edge weights (None until the first
-        # apply_edge_delta reweight — the legacy all-1.0 fill keeps
-        # pre-delta structure hashes bit-identical)
-        uw = svc._union_weights(nodes_u, union.graph.src, union.graph.dst)
+        # the version's per-pair edge weights (None until the first
+        # apply_edge_delta — the legacy all-1.0 fill keeps pre-delta
+        # structure hashes bit-identical)
+        uw = lookup_weights(snap.edge_table, snap.g.n_nodes,
+                            nodes_u[union.graph.src], nodes_u[union.graph.dst])
         w[:e_u] = 1.0 if uw is None else uw
 
         ca = np.zeros((n_pad, V))
@@ -358,19 +370,28 @@ class ServePipeline:
                 # assembled-but-abandoned job must not leave phantom stats)
                 svc._m_lumped_nodes.inc(asm.lump.lumped_nodes)
                 svc._m_reduction_ratio.observe(asm.lump.ratio)
+            # answers of a version a roll has since replaced are published
+            # but not cached: the roll's invalidation only reached entries
+            # cached before its swap
+            current = asm.version == svc._live.version
+            if not current:
+                svc._m_stale_uncached.inc(len(asm.todo))
             for j, (slot, fs, _entry) in enumerate(asm.todo):
                 loc = asm.locs[j]
                 auth_j, hub_j = asm.a[loc, j], asm.h[loc, j]
                 res_j = float(asm.res[j])
-                entry = _CacheEntry(nodes=fs.nodes, authority=auth_j,
-                                    hub=hub_j, residual=res_j)
-                svc._cache_put(fs.key, entry)
+                if current:
+                    svc._cache_put(fs.key, _CacheEntry(
+                        nodes=fs.nodes, authority=auth_j, hub=hub_j,
+                        residual=res_j))
+                # a warm start need not be exact: any version's will do
                 svc._warm_h[fs.nodes] = hub_j
                 svc._warm_seen[fs.nodes] = True
                 asm.results[slot] = QueryResult(
                     roots=fs.nodes[fs.roots_local], nodes=fs.nodes,
                     authority=auth_j, hub=hub_j, iters=int(asm.conv[j]),
-                    status=asm.statuses[j], key=fs.key, residual=res_j)
+                    status=asm.statuses[j], key=fs.key, residual=res_j,
+                    graph_version=asm.version)
             for slot, owner in asm.dups:  # identical root sets share a col
                 asm.results[slot] = asm.results[owner]
                 svc.stats[asm.results[owner].status] += 1

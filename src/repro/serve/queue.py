@@ -284,13 +284,15 @@ class RankQueue:
 
     def _shed_result(self, roots_u: np.ndarray, key: str):
         """A ``QueryResult`` carrying the shed verdict: the request's own
-        roots as the node set, zero scores, ``status="shed"`` — shaped
-        like a served result so fan-out code needs no special case."""
+        roots as the node set, zero scores, ``status="shed"``, the live
+        graph version — shaped like a served result so fan-out code needs
+        no special case."""
         from .rank_service import QueryResult
         n = len(roots_u)
         return QueryResult(roots=roots_u, nodes=roots_u.copy(),
                            authority=np.zeros(n), hub=np.zeros(n),
-                           iters=0, status="shed", key=key)
+                           iters=0, status="shed", key=key,
+                           graph_version=self.service.graph_version)
 
     def _shed(self, tickets: List[QueueTicket], roots_u: np.ndarray):
         # shed tickets resolve in microseconds; their ~0ms latencies must

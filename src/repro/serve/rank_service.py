@@ -41,6 +41,16 @@ assemble → plan → sweep → publish through one ``ServePipeline``, which at
 ``pipeline_depth >= 2`` overlaps the next batch's host work with the
 current batch's device sweep.
 
+**Live graph.** The served graph is versioned (``GraphVersion``): version
+0 at construction, one more for each delta ``apply_edge_delta`` rolls
+in. A roll builds the next version off the service lock while batches
+keep sweeping, then swaps it in atomically; admission never closes. Each
+batch is assembled on one version, and every ``QueryResult`` carries the
+``graph_version`` it is exact for. A request submitted after a roll's
+acknowledgement is answered at that version or a later one, and an
+answer computed on a version that a roll has since replaced is published
+but never cached.
+
 Every layer counts into one typed ``serve.telemetry.MetricsRegistry``
 (``self.telemetry``; the legacy ``stats`` dict is a live alias view over
 it). ``docs/ARCHITECTURE.md`` is the end-to-end tour of this stack;
@@ -51,7 +61,8 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-from collections import OrderedDict
+from collections import OrderedDict, deque
+from contextlib import nullcontext
 from typing import Dict, List, Optional, Sequence
 
 import jax.numpy as jnp
@@ -60,8 +71,9 @@ import numpy as np
 from ..graph.structure import Graph
 from ..graph.subgraph import FocusedSubgraph, SubgraphExtractor
 from .backends import SweepBackend, SweepBatch, make_backend, select_backend
-from .delta import EdgeDelta, apply_to_graph, lookup_weights
+from .delta import EdgeDelta, EdgeTable, apply_to_graph
 from .plans import PlanCache, SweepPlan, topology_key
+from .telemetry import span
 
 
 @dataclasses.dataclass
@@ -140,12 +152,29 @@ class QueryResult:
     # precision ladder (and the legacy loop) publishes. None only for
     # results cached before certificates existed (old spill records).
     residual: Optional[float] = None
+    # the graph version the answer is exact for: its batch's version (a
+    # cache hit: the version current at the probe, as every cached entry
+    # is valid for the current version)
+    graph_version: int = 0
 
     def topk(self, k: int = 10):
         """Top-k (global node id, authority score) pairs."""
         order = np.argsort(-self.authority)[:k]
         return [(int(self.nodes[i]), float(self.authority[i]))
                 for i in order]
+
+
+@dataclasses.dataclass(frozen=True)
+class GraphVersion:
+    """One version of the served graph, as a batch is assembled on it:
+    the graph, its extractor and its edge-weight table (None until the first
+    delta). ``version`` counts the deltas applied since the
+    service was built."""
+
+    version: int
+    g: Graph
+    extractor: SubgraphExtractor
+    edge_table: Optional[EdgeTable] = None
 
 
 @dataclasses.dataclass
@@ -160,7 +189,6 @@ class RankService:
     """Batched, cached, warm-starting query-ranking front end over one graph."""
 
     def __init__(self, g: Graph, config: Optional[RankServiceConfig] = None):
-        self.g = g
         self.cfg = config or RankServiceConfig()
         # without jax_enable_x64 a float64 request silently runs fp32, whose
         # residual floor (~1e-7) never reaches the default tol — every cold
@@ -225,8 +253,11 @@ class RankService:
         # "off" normalizes to None (mirroring the ladder) so the disabled
         # path touches no lumping code and stays bit-identical
         self._lumping = None if self.cfg.lumping == "off" else self.cfg.lumping
-        self.extractor = SubgraphExtractor(g, self.cfg.out_cap,
-                                           self.cfg.in_cap)
+        # the live graph version; replaced whole, under the lock, by a roll
+        self._live = GraphVersion(0, g, SubgraphExtractor(
+            g, self.cfg.out_cap, self.cfg.in_cap))
+        # one roll at a time: each builds on the version before it
+        self._roll_lock = threading.Lock()
         self._backends: Dict[str, SweepBackend] = {}
         self._cache: OrderedDict[str, _CacheEntry] = OrderedDict()
         self._plans = PlanCache(self.cfg.plan_cache_size)
@@ -285,16 +316,23 @@ class RankService:
         # live edge-delta rolls (apply_edge_delta / the lazy plan patching
         # it arms): plans value-patched (labeled by the backend that
         # patched) vs fully replanned, result-cache entries invalidated,
-        # and the swap's wall time
+        # the roll's spans, what the deltas carried, the live version, and
+        # answers of a replaced version kept out of the cache
         from .backends import BACKENDS
         for b in BACKENDS:
             reg.counter("service.delta.patched", b)
         self._m_delta_replanned = reg.counter("service.delta.replanned")
         self._m_delta_invalidated = reg.counter("service.delta.invalidated")
-        self._m_delta_swap = reg.histogram("service.delta.swap_ms")
-        # per-pair edge weights, None until the first delta (all-1.0 —
-        # keeps every pre-delta structure hash and code path bit-identical)
-        self._edge_table = None
+        self._m_delta = {stage: reg.histogram(f"service.delta.{stage}_ms")
+                         for stage in ("roll", "apply", "extract", "swap")}
+        self._m_links_added = reg.counter("delta.links_added")
+        self._m_links_removed = reg.counter("delta.links_removed")
+        self._m_pages_added = reg.counter("delta.pages_added")
+        self._m_stale_uncached = reg.counter("service.stale_uncached")
+        self._m_version = reg.gauge("service.graph_version")
+        # (version, stage, t0, t1) of each applied roll's spans, on
+        # time.perf_counter, like ServePipeline.trace
+        self.delta_trace = deque(maxlen=256)
         # weight-blind plan index: topo key -> the newest full cache key
         # with that topology, so a post-reweight batch can patch the
         # predecessor plan instead of rebuilding (see _plan_for)
@@ -314,6 +352,20 @@ class RankService:
             self.gc_spill()  # compact stale generations + crash droppings
         from .pipeline import ServePipeline
         self.pipeline = ServePipeline(self, depth=self.cfg.pipeline_depth)
+
+    # -- the live graph version -------------------------------------------
+
+    @property
+    def g(self) -> Graph:
+        return self._live.g
+
+    @property
+    def extractor(self) -> SubgraphExtractor:
+        return self._live.extractor
+
+    @property
+    def graph_version(self) -> int:
+        return self._live.version
 
     def queue(self, **kw):
         """An async micro-batching frontend over this service (the config's
@@ -542,16 +594,23 @@ class RankService:
         ``checkpoint.save`` on the same key's generation — and are
         best-effort: durability failures (disk full, permissions) must
         never fail a batch whose results are already in memory.
+
+        The pending list is taken under the IO lock, which a roll's swap
+        also holds: writes taken before a swap land before its generation
+        bump (and read as absent after it), and writes still pending at
+        the swap are filtered by it, so no pre-delta vector is ever
+        written under the new generation.
         """
         if self._spill is None:
             return
         with self._lock:
-            pending, self._spill_pending = self._spill_pending, []
-        if not pending:
-            return  # don't queue behind another thread's writes for a no-op
+            if not self._spill_pending:
+                return  # don't queue behind another thread's writes
         import time
         written = 0
         with self._spill_io_lock:
+            with self._lock:
+                pending, self._spill_pending = self._spill_pending, []
             for key, nodes, authority, hub in pending:
                 t0 = time.perf_counter()
                 try:
@@ -588,10 +647,12 @@ class RankService:
             raise ValueError("no spill_dir configured")
         self._drain_spill()  # deferred evictee writes aren't in the LRU
         import time
-        with self._lock:
-            entries = [(k, e.nodes, e.authority, e.hub)
-                       for k, e in self._cache.items()]
         with self._spill_io_lock:
+            # taken under the IO lock, as _drain_spill takes its list: a
+            # roll's swap cannot fall between the copy and the writes
+            with self._lock:
+                entries = [(k, e.nodes, e.authority, e.hub)
+                           for k, e in self._cache.items()]
             for key, nodes, authority, hub in entries:
                 t0 = time.perf_counter()
                 self._spill.put(key, nodes, authority, hub)
@@ -635,22 +696,38 @@ class RankService:
             with self._spill_io_lock:
                 self._spill.bump_data_generation()
 
-    def apply_edge_delta(self, adds=None, removes=None,
-                         reweights=None) -> dict:
-        """Roll an edge changeset into the running service (live graph
-        mutation — no restart, no cold caches; see ``serve.delta``).
+    def apply_edge_delta(self, adds=None, removes=None, reweights=None,
+                         pages: int = 0) -> dict:
+        """Roll a changeset into the running service (live graph mutation
+        — no restart, no cold caches, no pause in admission; see
+        ``serve.delta``). Returns the acknowledgement: a summary whose
+        ``version`` is the new graph version.
 
         ``adds``: (src, dst) or (src, dst, w) rows; ``removes``: (src,
-        dst) rows; ``reweights``: (src, dst, w) rows. Weights must be
-        finite and nonzero (reweight-to-0 is a remove). Node ids are
-        fixed at construction — deltas change edges only.
+        dst) rows; ``reweights``: (src, dst, w) rows; ``pages``: new pages,
+        ids ``n .. n + pages - 1`` for an ``n``-page graph, which the rows
+        may name. Weights must be finite and nonzero (reweight-to-0 is a
+        remove).
+
+        The roll (span ``delta.roll``) builds the next version — graph and
+        weight table (``delta.apply``), extractor (``delta.extract``,
+        structural deltas only) — outside the service lock, while batches
+        keep assembling and sweeping on the current one. It then swaps it
+        in under the lock (``delta.swap``), with the invalidation below,
+        and acknowledges. Queue admission stays open throughout: a batch
+        assembled before the swap finishes on the version it was
+        assembled on and is stamped with it; its answers are published
+        but kept out of the cache (``service.stale_uncached``). A request
+        submitted after the acknowledgement is assembled after the swap,
+        so it is answered at the new version or a later one. Rolls run
+        one at a time.
 
         What survives, by design:
 
-        * **warm table** — entirely (the tentpole carry-over): post-delta
-          refreshes warm-start from the pre-delta fixed points, which the
-          paper's acceleration premise makes converge in a handful of
-          sweeps instead of from uniform.
+        * **warm table** — entirely (the tentpole carry-over), grown by
+          the new pages: post-delta refreshes warm-start from the
+          pre-delta fixed points, which the paper's acceleration premise
+          makes converge in a handful of sweeps instead of from uniform.
         * **plans** — weight-only deltas keep every topology, so the next
           lookup value-patches the cached layout (``SweepBackend.patch``
           via the weight-blind topology index; ``service.delta.patched``)
@@ -662,50 +739,76 @@ class RankService:
           (``service.delta.invalidated``); the rest keep serving as hits.
 
         What cannot survive: pre-delta vectors for touched subgraphs —
-        in memory (invalidated here), in flight to disk (pending writes
-        dropped), and on disk (the spill's data generation bumps, so the
-        disk fallback and restart-restore read them as absent; surviving
-        entries re-spill under the new generation when ``spill_policy``
-        is "all").
-
-        Thread-safe, but the intended call pattern is inside a queue
-        drain window (drain -> apply_edge_delta -> undrain, see
-        ``launch.serve_rank.roll_delta``) so no batch is mid-flight
-        against the pre-delta graph. Returns a summary dict; timing goes
-        to ``service.delta.swap_ms``.
+        in memory (invalidated at the swap), in flight to disk (pending
+        writes dropped), and on disk (the spill's data generation bumps
+        in the same critical section, so the disk fallback and
+        restart-restore read them as absent; surviving entries re-spill
+        under the new generation when ``spill_policy`` is "all").
         """
-        import time
-        t0 = time.perf_counter()
-        delta = EdgeDelta.normalize(adds, removes, reweights,
-                                    self.g.n_nodes)
-        if delta.empty:
-            return {"structural": False, "invalidated": 0,
-                    "touched_nodes": 0, "data_generation": None,
-                    "swap_ms": 0.0}
-        new_g, table = apply_to_graph(self.g, self._edge_table, delta)
-        touched = delta.touched_nodes()
-        with self._lock:
+        with self._roll_lock, span("delta.roll", self._m_delta["roll"]) \
+                as sp_roll:
+            base = self._live
+            delta = EdgeDelta.normalize(adds, removes, reweights,
+                                        base.g.n_nodes, pages)
+            if delta.empty:
+                return {"version": base.version, "structural": False,
+                        "pages": 0, "invalidated": 0, "touched_nodes": 0,
+                        "data_generation": None, "swap_ms": 0.0,
+                        "roll_ms": 0.0}
+            with span("delta.apply", self._m_delta["apply"]) as sp_apply:
+                new_g, table = apply_to_graph(base.g, base.edge_table, delta)
+            spans = [("apply", sp_apply)]
+            extractor = base.extractor
             if delta.structural:
-                self.g = new_g
-                self.extractor = SubgraphExtractor(new_g, self.cfg.out_cap,
-                                                   self.cfg.in_cap)
-            self._edge_table = table
-            doomed = {k for k, e in self._cache.items()
-                      if np.isin(e.nodes, touched,
-                                 assume_unique=True).any()}
-            for k in doomed:
-                del self._cache[k]
-            self._m_delta_invalidated.inc(len(doomed))
-            # in-flight writes of now-stale vectors must not reach disk
-            self._spill_pending = [p for p in self._spill_pending
-                                   if p[0] not in doomed]
-            survivors = [(k, e.nodes, e.authority, e.hub)
-                         for k, e in self._cache.items()]
-        gen = None
-        if self._spill is not None:
-            with self._spill_io_lock:
-                gen = self._spill.bump_data_generation()
-            if self.cfg.spill_policy == "all" and survivors:
+                with span("delta.extract", self._m_delta["extract"]) as sp:
+                    extractor = SubgraphExtractor(new_g, self.cfg.out_cap,
+                                                  self.cfg.in_cap)
+                spans.append(("extract", sp))
+            touched = delta.touched_nodes()
+            # the spill's generation bumps in the swap's critical section,
+            # which holds the spill IO lock (lock order: spill IO, then
+            # service): no write is in flight across it (_drain_spill takes
+            # its list under that lock), pending writes of touched vectors
+            # are dropped below, and a disk read from before the bump is
+            # not admitted at the new version (pipeline.assemble)
+            io = self._spill_io_lock if self._spill is not None \
+                else nullcontext()
+            gen = None
+            with io, self._lock, \
+                    span("delta.swap", self._m_delta["swap"]) as sp_swap:
+                version = base.version + 1
+                self._live = GraphVersion(version, new_g, extractor, table)
+                if delta.pages:
+                    self._warm_h = np.concatenate(
+                        [self._warm_h, np.zeros(delta.pages)])
+                    self._warm_seen = np.concatenate(
+                        [self._warm_seen, np.zeros(delta.pages, bool)])
+                doomed = {k for k, e in self._cache.items()
+                          if np.isin(e.nodes, touched,
+                                     assume_unique=True).any()}
+                for k in doomed:
+                    del self._cache[k]
+                # pending writes of now-stale vectors must not reach disk:
+                # evictees (policy "evict") are no longer in the cache, so
+                # filter by the touched pages, not by the doomed keys
+                self._spill_pending = [
+                    p for p in self._spill_pending
+                    if not np.isin(p[1], touched, assume_unique=True).any()]
+                survivors = [(k, e.nodes, e.authority, e.hub)
+                             for k, e in self._cache.items()]
+                if self._spill is not None:
+                    gen = self._spill.bump_data_generation()
+                self._m_delta_invalidated.inc(len(doomed))
+                self._m_links_added.inc(len(np.unique(
+                    delta.adds[:, 0] * new_g.n_nodes + delta.adds[:, 1])))
+                self._m_links_removed.inc(len(np.unique(
+                    delta.removes[:, 0] * new_g.n_nodes
+                    + delta.removes[:, 1])))
+                self._m_pages_added.inc(delta.pages)
+                self._m_version.set(version)
+            spans.append(("swap", sp_swap))
+            if gen is not None and self.cfg.spill_policy == "all" \
+                    and survivors:
                 # everything on disk just went stale; re-spill the still-
                 # valid entries under the new generation so a restart
                 # keeps them (only pre-delta state for touched subgraphs
@@ -713,24 +816,15 @@ class RankService:
                 with self._lock:
                     self._spill_pending.extend(survivors)
                 self._drain_spill()
-        swap_ms = (time.perf_counter() - t0) * 1e3
-        self._m_delta_swap.observe(swap_ms)
-        return {"structural": delta.structural,
-                "invalidated": len(doomed),
+        spans.append(("roll", sp_roll))
+        self.delta_trace.extend((version, stage, sp.t0, sp.t1)
+                                for stage, sp in spans)
+        return {"version": version, "structural": delta.structural,
+                "pages": delta.pages, "invalidated": len(doomed),
                 "touched_nodes": int(len(touched)),
-                "data_generation": gen, "swap_ms": swap_ms}
-
-    def _union_weights(self, nodes: np.ndarray, src_loc: np.ndarray,
-                       dst_loc: np.ndarray) -> Optional[np.ndarray]:
-        """Per-edge weights for a union subgraph's induced edges (local
-        endpoint arrays + the local->global node map), or None when no
-        delta has ever reweighted anything (all 1.0 — the assemble stage
-        keeps its legacy constant fill and bit-identical hashes)."""
-        table = self._edge_table
-        if table is None:
-            return None
-        return lookup_weights(table, self.g.n_nodes,
-                              nodes[src_loc], nodes[dst_loc])
+                "data_generation": gen,
+                "swap_ms": (sp_swap.t1 - sp_swap.t0) * 1e3,
+                "roll_ms": (sp_roll.t1 - sp_roll.t0) * 1e3}
 
     def snapshot_stats(self) -> dict:
         """A consistent copy of the stats counters (the legacy key set).
@@ -785,10 +879,11 @@ class RankService:
         roots_u = np.unique(arr.astype(np.int64))
         if len(roots_u) == 0:
             raise ValueError("empty root set")
-        if roots_u[0] < 0 or roots_u[-1] >= self.g.n_nodes:
+        n = self.g.n_nodes  # the live version's: a later one only grows
+        if roots_u[0] < 0 or roots_u[-1] >= n:
             # negative ids would silently wrap through numpy indexing
             raise ValueError(
-                f"root ids must be in [0, {self.g.n_nodes}); got "
+                f"root ids must be in [0, {n}); got "
                 f"[{roots_u[0]}, {roots_u[-1]}]")
         return roots_u.astype(np.int32)
 

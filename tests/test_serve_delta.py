@@ -305,9 +305,11 @@ def test_empty_delta_is_a_noop(g):
     roots = np.array([90, 91])
     svc.rank([roots])
     summ = svc.apply_edge_delta()
-    assert summ == {"structural": False, "invalidated": 0,
-                    "touched_nodes": 0, "data_generation": None,
-                    "swap_ms": 0.0}
+    assert summ == {"version": 0, "structural": False, "pages": 0,
+                    "invalidated": 0, "touched_nodes": 0,
+                    "data_generation": None, "swap_ms": 0.0,
+                    "roll_ms": 0.0}
+    assert svc.graph_version == 0
     assert svc.rank([roots])[0].status == "hit"
 
 

@@ -1,8 +1,9 @@
 """Program spans on the profiler's clock (serve.telemetry.span): a queued
-burst that hits backpressure and one whole-graph engine job, traced with
-``jax.profiler`` on the CPU, must leave every span of the runbook's span
-table on the host plane, nested as the layers nest and sharing their ids;
-and the queue's admission span must count the backpressure wait."""
+burst that hits backpressure, a live graph roll and one whole-graph engine
+job, traced with ``jax.profiler`` on the CPU, must leave every span of the
+runbook's span table on the host plane, nested as the layers nest and
+sharing their ids; and the queue's admission span must count the
+backpressure wait."""
 import glob
 import os
 import re
@@ -20,7 +21,7 @@ from repro.serve.telemetry import MetricsRegistry, span
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 RUNBOOK = os.path.join(ROOT, "docs", "OPERATIONS.md")
-PREFIXES = ("queue.", "pipeline.", "backend.", "engine.")
+PREFIXES = ("queue.", "pipeline.", "backend.", "engine.", "delta.")
 
 
 @pytest.fixture(scope="module")
@@ -59,8 +60,9 @@ def documented_spans():
 
 @pytest.fixture(scope="module")
 def traced(g, queries, tmp_path_factory):
-    """One queued burst (``max_pending`` 2, so ``submit`` blocks) and one
-    engine job under the profiler, after a warm-up that compiles both."""
+    """One queued burst (``max_pending`` 2, so ``submit`` blocks), a roll
+    that adds a page and one engine job under the profiler, after a
+    warm-up that compiles both."""
     svc = RankService(g, RankServiceConfig(v_max=4, tol=1e-10))
     svc.rank(queries[:4])
     RankingEngine(g, n_shards=2).run(tol=1e-8)
@@ -70,6 +72,7 @@ def traced(g, queries, tmp_path_factory):
         tickets = [q.submit(r) for r in queries]
         assert all(t.result(timeout=300) is not None for t in tickets)
         q.close()
+        svc.apply_edge_delta(adds=[(0, g.n_nodes)], pages=1)
         res = RankingEngine(g, n_shards=2).run(tol=1e-8)
     return host_spans(d), res
 
@@ -125,6 +128,15 @@ def test_backend_spans_nest_in_pipeline_sweep_with_batch_ids(traced):
             assert (f"pipeline.{stage}", j) in stages
     waits = {s[4]["batch"] for s in spans if s[0] == "queue.flush_wait"}
     assert batches <= waits
+
+
+def test_delta_spans_nest_in_the_roll(traced):
+    spans, _res = traced
+    (roll,) = [s for s in spans if s[0] == "delta.roll"]
+    inner = [s for s in spans if s[0].startswith("delta.") and s is not roll]
+    assert [s[0] for s in inner] == ["delta.apply", "delta.extract",
+                                     "delta.swap"]
+    assert all(_inside(s, roll) for s in inner)
 
 
 def test_admit_span_covers_the_backpressure_wait(traced):
