@@ -98,6 +98,23 @@ def accel_hits(g: Graph, tol=1e-10, max_iter=2000, v=1, dtype=jnp.float64,
     return _finalize(edges, res, ca=ca, ch=ch, zeta=zeta)
 
 
+def column_sweep(authority, hub, ca, ch, mask):
+    """The masked multi-query sweep over two passes: ``authority(x)`` sums
+    x over each page's in-links, ``hub(x)`` over its out-links, both (N, V)
+    -> (N, V). ``hits_sweep_cols`` is it over an edge list's scatter-adds;
+    the serve path's dense backend runs it over sorted segment sums
+    (``sparse.segsum``)."""
+
+    def sweep(h):
+        with jax.named_scope("hits.authority"):
+            a = authority(h * ch) * mask
+        with jax.named_scope("hits.hub"):
+            h_new = hub(a * ca) * mask
+        return normalize_l1(h_new, axis=0), a
+
+    return sweep
+
+
 def hits_sweep_cols(edges: EdgeList, ca, ch, mask):
     """Multi-query sweep: ca/ch/mask are (N, V); column j is accelerated
     HITS restricted to its own focused node set.
@@ -110,16 +127,10 @@ def hits_sweep_cols(edges: EdgeList, ca, ch, mask):
     therefore serves V independent query-focused rankings (the (N, V)
     multi-vector path of DESIGN.md §3, driven per-query).
     """
-
-    def sweep(h):
-        with jax.named_scope("hits.authority"):
-            a = spmv_dst(h * ch, edges.src, edges.dst, edges.n, edges.w) * mask
-        with jax.named_scope("hits.hub"):
-            h_new = spmv_src(a * ca, edges.src, edges.dst, edges.n,
-                             edges.w) * mask
-        return normalize_l1(h_new, axis=0), a
-
-    return sweep
+    return column_sweep(
+        lambda x: spmv_dst(x, edges.src, edges.dst, edges.n, edges.w),
+        lambda x: spmv_src(x, edges.src, edges.dst, edges.n, edges.w),
+        ca, ch, mask)
 
 
 def authority_sweep(edges: EdgeList, ca=None, ch=None, zeta: float = 1.0):

@@ -5,8 +5,10 @@
 masks, and a sentinel-padded edge list — and hands it to a backend that
 runs the masked multi-column accelerated-HITS convergence loop:
 
-* ``dense``   — single-device ``core.hits.hits_sweep_cols`` under a jitted
-                ``lax.while_loop`` (the PR-1 path, extracted).
+* ``dense``   — single-device ``core.hits.column_sweep`` under a jitted
+                ``lax.while_loop``, each pass a sorted segment sum
+                (``sparse.segsum``) over edges the plan orders by
+                destination and by source: no scatter.
 * ``sharded`` — the same column sweep lowered onto a device mesh through
                 ``sparse.dist.make_dist_hits_sweep_cols``; edge shards
                 follow the dist ladder (``replicated``: 2 psums/sweep,
@@ -22,8 +24,9 @@ runs the masked multi-column accelerated-HITS convergence loop:
 
 Each backend splits its work along the plan/sweep seam (``serve.plans``):
 ``plan(batch)`` builds the graph-structure-only artifact — device edge
-list (dense), pow2-bucketed device edge shards + the shared mesh
-(sharded), blocking permutation + both BSR structures (bsr) — and
+list sorted by destination and by source (dense), pow2-bucketed device
+edge shards + the shared mesh (sharded), blocking permutation + both BSR
+structures (bsr) — and
 ``sweep(plan, batch)`` runs the convergence loop against it.
 ``converge(batch)`` is the uncached composition; ``RankService`` LRU-caches
 plans per union-subgraph hash so repeat traffic skips all host-side layout
@@ -53,7 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..core.hits import EdgeList, hits_sweep_cols
+from ..core.hits import column_sweep
 from ..core.reordering import blocking_permutation
 from ..graph.structure import Graph
 from ..kernels.bsr_spmm import resolve_interpret
@@ -63,6 +66,8 @@ from ..sparse.dist import (build_edge_shards_cols,
                            device_put_edge_args_cols,
                            make_dist_hits_sweep_cols,
                            wire_bytes_from_collectives)
+from ..sparse.segsum import (SortedEdges, reweight, segment_sum_cols,
+                             sort_edges)
 from ..sparse.spmv import normalize_l1
 from .plans import (BsrPlan, DensePlan, ShardedPlan, SweepPlan,
                     structure_key)
@@ -257,10 +262,11 @@ class SweepBackend:
 
 @partial(jax.jit, static_argnames=("max_iter", "rank_k", "stable_sweeps",
                                    "bulk_dtype"))
-def _converge_batch(h0, src, dst, w, ca, ch, mask, tol, max_iter,
+def _converge_batch(h0, by_dst, by_src, ca, ch, mask, tol, max_iter,
                     rank_k=0, stable_sweeps=2, bulk_dtype=None,
                     bulk_tol=0.0):
-    """On-device convergence loop for V masked columns.
+    """On-device convergence loop for V masked columns, over the union's
+    edges sorted by destination and by source (``DensePlan``).
 
     Per-column L1 residuals; ``conv[j]`` records the sweep at which column
     j first hit tol (-1 while running). All columns keep sweeping until the
@@ -276,10 +282,17 @@ def _converge_batch(h0, src, dst, w, ca, ch, mask, tol, max_iter,
     Returns (h, a, conv, res) — ``res`` is the per-column certificate
     ``‖sweep(h) − h‖₁`` from one extra full-precision sweep.
     """
-    edges = EdgeList(src, dst, h0.shape[0], w)
-    sweep = hits_sweep_cols(edges, ca, ch, mask)
-    k_eff = min(int(rank_k), h0.shape[0]) if rank_k else 0
-    v = h0.shape[1]
+    n, v = h0.shape
+
+    def sweep_at(dtype):
+        def pass_(edges):
+            edges = edges._replace(w=edges.w.astype(dtype))
+            return lambda x: segment_sum_cols(x, edges)
+        return column_sweep(pass_(by_dst), pass_(by_src), ca.astype(dtype),
+                            ch.astype(dtype), mask.astype(dtype))
+
+    sweep = sweep_at(h0.dtype)
+    k_eff = min(int(rank_k), n) if rank_k else 0
 
     def loop(sweep_fn, h_init, k_init, stop_tol):
         def body(state):
@@ -317,11 +330,8 @@ def _converge_batch(h0, src, dst, w, ca, ch, mask, tol, max_iter,
         # bulk phase: same loop at the cheap dtype, stopping at the dtype's
         # residual floor; its sweep count carries into the polish phase so
         # max_iter bounds total work
-        edges_lo = EdgeList(src, dst, h0.shape[0], w.astype(bulk_dtype))
-        sweep_lo = hits_sweep_cols(edges_lo, ca.astype(bulk_dtype),
-                                   ch.astype(bulk_dtype),
-                                   mask.astype(bulk_dtype))
-        h_lo, k0, _ = loop(sweep_lo, h0.astype(bulk_dtype), k0, bulk_tol)
+        h_lo, k0, _ = loop(sweep_at(bulk_dtype), h0.astype(bulk_dtype), k0,
+                           bulk_tol)
         h0 = h_lo.astype(h0.dtype)
     h, k, conv = loop(sweep, h0, k0, tol)
     conv = jnp.where(conv < 0, k, conv)  # hit max_iter
@@ -335,35 +345,45 @@ def _converge_batch(h0, src, dst, w, ca, ch, mask, tol, max_iter,
 
 
 class DenseSweepBackend(SweepBackend):
-    """Single-device gather/segment-sum path (the semantic reference)."""
+    """Single-device sorted segment sums (the f64 serving path)."""
 
     name = "dense"
 
     def plan(self, b: SweepBatch, key: str = "") -> DensePlan:
-        # the dense "layout" is just the device-resident edge list: cached
-        # plans skip the per-batch host->device edge transfer
+        # the dense layout is the edge list by destination and by source,
+        # on the device: cached plans skip the sorts and the transfer
+        n_pad = b.h0.shape[0]
+        w = np.asarray(b.w, np.dtype(b.dtype))
+        with span("backend.order"):
+            by_dst = sort_edges(b.dst, b.src, w, n_pad)
+            by_src = sort_edges(b.src, b.dst, w, n_pad)
         return DensePlan(key=key or b.structure_key(), backend=self.name,
-                         n_pad=b.h0.shape[0], src=jnp.asarray(b.src),
-                         dst=jnp.asarray(b.dst), w=jnp.asarray(b.w, b.dtype))
+                         n_pad=n_pad, by_dst=by_dst, by_src=by_src)
 
     def plan_arrays(self, plan: DensePlan):
-        return ({"src": np.asarray(plan.src), "dst": np.asarray(plan.dst),
-                 "w": np.asarray(plan.w)}, {"n_pad": int(plan.n_pad)})
+        arrays = {f"{way}_{field}": np.asarray(x)
+                  for way, edges in (("by_dst", plan.by_dst),
+                                     ("by_src", plan.by_src))
+                  for field, x in edges._asdict().items()}
+        return arrays, {"n_pad": int(plan.n_pad)}
 
     def plan_restore(self, key: str, arrays, meta) -> DensePlan:
+        def edges(way):
+            return SortedEdges(*(jnp.asarray(arrays[f"{way}_{field}"])
+                                 for field in SortedEdges._fields))
         return DensePlan(key=key, backend=self.name,
-                         n_pad=int(meta["n_pad"]),
-                         src=jnp.asarray(arrays["src"]),
-                         dst=jnp.asarray(arrays["dst"]),
-                         w=jnp.asarray(arrays["w"]))
+                         n_pad=int(meta["n_pad"]), by_dst=edges("by_dst"),
+                         by_src=edges("by_src"))
 
     def patch(self, plan: DensePlan, b: SweepBatch,
               key: str = "") -> DensePlan:
-        # the endpoints are already on device; only the weight array ships
+        # the sorted endpoints are already on device; only the weight
+        # array ships, put into each order by its sort's permutation
         self._check(plan, b)
+        w = jnp.asarray(b.w, b.dtype)
         return DensePlan(key=key or b.structure_key(), backend=self.name,
-                         n_pad=plan.n_pad, src=plan.src, dst=plan.dst,
-                         w=jnp.asarray(b.w, b.dtype))
+                         n_pad=plan.n_pad, by_dst=reweight(plan.by_dst, w),
+                         by_src=reweight(plan.by_src, w))
 
     def sweep(self, plan: DensePlan, b: SweepBatch):
         self._check(plan, b)
@@ -373,7 +393,7 @@ class DenseSweepBackend(SweepBackend):
         with span("backend.converge"):
             # the readback below waits for these anyway: no extra sync
             out = jax.block_until_ready(_converge_batch(
-                h0, plan.src, plan.dst, plan.w, ca, ch, mask, b.tol,
+                h0, plan.by_dst, plan.by_src, ca, ch, mask, b.tol,
                 b.max_iter, rank_k=int(b.rank_k),
                 stable_sweeps=int(b.stable_sweeps),
                 bulk_dtype=b.ladder_key() or None, bulk_tol=b.bulk_tol()))

@@ -13,9 +13,10 @@ the layout cost once per distinct union subgraph.
 This module owns the abstraction:
 
 * ``SweepPlan``     — the backend-specific structural artifact. ``dense``:
-                      device-resident edge list; ``sharded``: pow2-bucketed
-                      edge shards on device + the shared mesh; ``bsr``: the
-                      blocking permutation and both DeviceBSR structures.
+                      the edge list by destination and by source;
+                      ``sharded``: pow2-bucketed edge shards on device +
+                      the shared mesh; ``bsr``: the blocking permutation
+                      and both DeviceBSR structures.
 * ``structure_key`` — content hash of the padded edge structure. Keys hash
                       the ACTUAL edges (not just the union node set), so a
                       mutated graph can never serve a stale plan: changed
@@ -96,11 +97,11 @@ class SweepPlan:
 
 @dataclasses.dataclass(frozen=True)
 class DensePlan(SweepPlan):
-    """Device-resident padded edge list (src/dst/w already shipped)."""
+    """The padded edge list on the device, in order by destination (the
+    authority pass) and by source (the hub pass)."""
 
-    src: object = None   # jnp (e_pad,) int32
-    dst: object = None
-    w: object = None     # jnp (e_pad,) sweep dtype
+    by_dst: object = None  # sparse.segsum.SortedEdges, e_pad edges
+    by_src: object = None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -242,10 +242,12 @@ class PlanSpill:
     they were never built for. 3 — plan-time lumping joined the cache key
     (a ``lump:<map-hash>`` marker on the stop tuple) and plans may now be
     built from lump-reduced arrays; pre-lumping records must not alias
-    reduced layouts they were never built for.
+    reduced layouts they were never built for. 4 — the dense plan holds
+    its edges sorted by destination and by source (``by_dst_*``,
+    ``by_src_*``) in place of the edge list.
     """
 
-    FORMAT = 3
+    FORMAT = 4
 
     def __init__(self, spill_dir: str, keep_generations: int = 1):
         self.dir = os.path.join(spill_dir, "plans")

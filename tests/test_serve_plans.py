@@ -9,6 +9,7 @@ graph can never be served a stale plan. Sharded device matrices run in a
 subprocess with ``--xla_force_host_platform_device_count=8`` (as in
 test_serve_backends).
 """
+import dataclasses
 import os
 import subprocess
 import sys
@@ -164,6 +165,36 @@ def test_mutated_graph_never_serves_stale_plan():
     again = be.sweep(cache.get(key1), b1)
     ref = be.converge(b1)
     assert np.abs(again[1] - ref[1]).sum() <= 1e-12
+
+
+@pytest.mark.parametrize("path", ["patch", "restore"])
+def test_dense_patched_and_restored_plans_match_fresh(path, tmp_path):
+    """The dense plan holds the edges in order by destination and by
+    source. A weight-only patch puts the new weights into both
+    orders; a spilled plan comes back from disk in its order. Either sweeps
+    to the same vectors, bit for bit, as a plan built fresh."""
+    from repro.serve.spill import PlanSpill
+    be = DenseSweepBackend()
+    # keys out of order both ways, pages sharing in- and out-links
+    edges = [(3, 1), (0, 2), (4, 1), (1, 3), (2, 1), (0, 4), (4, 3), (2, 0)]
+    old = _hand_batch(edges)
+    w = old.w.copy()
+    w[:len(edges)] = np.arange(1.0, len(edges) + 1)
+    new = dataclasses.replace(old, w=w)
+    fresh = be.sweep(be.plan(new), new)
+    if path == "patch":
+        plan = be.patch(be.plan(old), new, new.structure_key())
+        # the weights matter: the unpatched plan's vectors differ
+        stale = be.sweep(be.plan(old), new)
+        assert np.abs(stale[0] - fresh[0]).sum() > 1e-3
+    else:
+        spill = PlanSpill(str(tmp_path))
+        spill.put(("dense", (), new.structure_key()),
+                  *be.plan_arrays(be.plan(new)))
+        plan = be.plan_restore(new.structure_key(), *spill.get(
+            ("dense", (), new.structure_key())))
+    for got, want in zip(be.sweep(plan, new), fresh):
+        np.testing.assert_array_equal(got, want)
 
 
 # ------------------------------------------------ PlanCache unit behavior

@@ -111,11 +111,19 @@ def test_engine_spans_nest_with_job_and_sweep_ids(traced):
 def test_backend_spans_nest_in_pipeline_sweep_with_batch_ids(traced):
     spans, _res = traced
     sweeps = [s for s in spans if s[0] == "pipeline.sweep"]
-    backend = [s for s in spans if s[0].startswith("backend.")]
+    backend = [s for s in spans
+               if s[0].startswith("backend.") and s[0] != "backend.order"]
     assert sweeps and len(backend) % 3 == 0 and backend
     for b in backend:
         (owner,) = [s for s in sweeps if _inside(b, s)]
         assert b[4] == owner[4] and set(owner[4]) == {"run", "batch"}
+    # a plan built for a batch sorts its edges in the batch's plan stage
+    plans = [s for s in spans if s[0] == "pipeline.plan"]
+    orders = [s for s in spans if s[0] == "backend.order"]
+    assert orders
+    for o in orders:
+        (owner,) = [s for s in plans if _inside(o, s)]
+        assert o[4] == owner[4]
     # each batch's stages share its ids, and the queue's flush wait for a
     # batch carries the same batch index
     runs = {s[4]["run"] for s in sweeps}

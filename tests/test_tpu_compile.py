@@ -146,6 +146,14 @@ def test_engine_segment_sum_compiles_without_scatter(one_chip, dtype):
 # ------------------------------------------------------- dense and sharded
 
 
+def _sorted_edges(sharding, n_pad, e_pad, dtype):
+    from repro.sparse.segsum import SortedEdges
+    i32, b = jnp.int32, jnp.bool_
+    return SortedEdges(*(_shape(sharding, shape, dt) for shape, dt in (
+        ((e_pad,), i32), ((e_pad,), dtype), ((e_pad,), b), ((n_pad,), i32),
+        ((n_pad,), b), ((e_pad,), i32))))
+
+
 @pytest.mark.parametrize("shape,rank_k,ladder", [
     ((N_PAD, E_PAD), 0, None), (SMALL, 10, None), (SMALL, 0, "bfloat16"),
     (SMALL, 0, "float32")])
@@ -153,16 +161,19 @@ def test_dense_f64_loop_compiles(one_chip, shape, rank_k, ladder):
     """The dense f64 convergence loop: at a Kleinberg-size union as the
     service runs it by default (no Mosaic anywhere), and at a
     launcher-default union with ``lax.top_k`` on f64 for the rank-stable
-    exit, or with the precision ladder's low-precision bulk phase."""
+    exit, or with the precision ladder's low-precision bulk phase. Each
+    pass is a gather and a scan over the plan's sorted edges, with no
+    scatter left in the program."""
     s = lambda shp, dt: _shape(one_chip, shp, dt)  # noqa: E731
-    f64, i32 = jnp.float64, jnp.int32
+    f64 = jnp.float64
     n_pad, e_pad = shape
     vec = s((n_pad, V), f64)
+    edges = _sorted_edges(one_chip, n_pad, e_pad, f64)
     compiled = backends._converge_batch.lower(
-        vec, s((e_pad,), i32), s((e_pad,), i32), s((e_pad,), f64), vec, vec,
-        vec, s((), f64), 1000, rank_k=rank_k, bulk_dtype=ladder,
-        bulk_tol=1e-3).compile()
+        vec, edges, edges, vec, vec, vec, s((), f64), 1000, rank_k=rank_k,
+        bulk_dtype=ladder, bulk_tol=1e-3).compile()
     _compiled_ok(compiled, kernel=False)
+    assert not re.search(r"\bscatter\(", compiled.as_text())  # an HLO op
     mem = compiled.memory_analysis()
     # fits one 16 GB chip with room for the plan cache
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
