@@ -1,7 +1,10 @@
-"""repro: accelerated-HITS ranking + multi-pod JAX training framework.
+"""repro: accelerated HITS as a ranking system in JAX.
 
 Reproduces and extends Mirzal & Furukawa (2009), "A Method for Accelerating
-the HITS Algorithm". See DESIGN.md for the system map.
+the HITS Algorithm": whole-graph ranking (``core.engine``, run by
+``launch.rank``) and query-time HITS served in batches over a live index
+(``serve.RankService``, run by ``launch.serve_rank``). ``docs/ARCHITECTURE.md``
+is the system map.
 """
 
 __version__ = "1.0.0"

@@ -3,7 +3,7 @@
 Layout: <dir>/step_<k>/arrays.npz + manifest.json. Writes go to a temp dir
 and are os.replace'd into place, so a preemption mid-write never corrupts
 the latest checkpoint. ``latest_step``/``restore`` drive cold restarts; the
-ranking engine and the training loop both checkpoint through this module.
+ranking engine checkpoints through this module.
 """
 from __future__ import annotations
 

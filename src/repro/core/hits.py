@@ -2,7 +2,7 @@
 
 Both are expressed as sweeps over a device-resident edge list and run under
 the shared power engine. Vectors may be multi-column (N, V) — V independent
-ranking vectors per traversal (personalized/topic HITS; see DESIGN.md §3).
+ranking vectors per traversal (personalized/topic HITS).
 """
 from __future__ import annotations
 
@@ -125,7 +125,7 @@ def hits_sweep_cols(edges: EdgeList, ca, ch, mask):
     then removes scatter into off-support nodes, making the column operator
     exactly P_j·L·P_j — the induced subgraph of S_j. One edge traversal
     therefore serves V independent query-focused rankings (the (N, V)
-    multi-vector path of DESIGN.md §3, driven per-query).
+    multi-vector path, driven per-query).
     """
     return column_sweep(
         lambda x: spmv_dst(x, edges.src, edges.dst, edges.n, edges.w),

@@ -1,16 +1,13 @@
 from .structure import BSR, CSR, Graph, padded_neighbors, to_bsr, to_csr
 from .generators import (PAPER_TABLE7, WebGraphSpec, all_paper_datasets,
-                         bipartite_interactions, generate_webgraph,
-                         paper_dataset)
+                         generate_webgraph, paper_dataset)
 from .partition import partition_edges, partition_edges_by_dst_block
-from .sampler import SampledSubgraph, SamplerTables, khop_sizes, sample_khop
 from .subgraph import FocusedSubgraph, SubgraphExtractor, root_set_key
 
 __all__ = [
     "BSR", "CSR", "Graph", "padded_neighbors", "to_bsr", "to_csr",
     "PAPER_TABLE7", "WebGraphSpec", "all_paper_datasets",
-    "bipartite_interactions", "generate_webgraph", "paper_dataset",
+    "generate_webgraph", "paper_dataset",
     "partition_edges", "partition_edges_by_dst_block",
-    "SampledSubgraph", "SamplerTables", "khop_sizes", "sample_khop",
     "FocusedSubgraph", "SubgraphExtractor", "root_set_key",
 ]

@@ -8,13 +8,13 @@ set. Dong et al. motivate shrinking the per-query iteration space; this
 module does it structurally.
 
 Expansion reads the padded neighbor tables of ``graph.structure``
-(the same ``padded_neighbors`` the sampler builds on, over the forward and
-reversed graph), so the caps are the same degree-truncation the sampler
-applies. Neighbors are listed in page-id order, so where a page has more
-than the cap, the ones with the smallest ids are kept: a base set depends
-on the graph's links alone, not on the order of its edge list (a live
-delta appends its links at the end). Everything is host-side numpy —
-extraction is preprocessing, like the rest of ``graph.structure``.
+(``padded_neighbors``, over the forward and reversed graph), so the caps
+truncate each page's degree. Neighbors are listed in page-id order, so
+where a page has more than the cap, the ones with the smallest ids are
+kept: a base set depends on the graph's links alone, not on the order of
+its edge list (a live delta appends its links at the end). Everything is
+host-side numpy — extraction is preprocessing, like the rest of
+``graph.structure``.
 """
 from __future__ import annotations
 

@@ -2,19 +2,14 @@
 
 bsr_spmm: block-sparse adjacency x multi-vector with fused Ca/Ch scaling
           (the accelerated-HITS sweep hot path).
-seg_matmul: tiled segment-sum as one-hot MXU matmul (GNN aggregation,
-          EmbeddingBag reduce, HITS edge scatter).
 Validated in interpret=True mode against ref.py oracles; TPU is the target.
 """
 from .bsr_spmm import bsr_converge_cols, bsr_scaled_matvec, resolve_interpret
-from .ops import (DeviceBSR, bsr_converge, bsr_matvec, build_tiled_segments,
-                  classify_exit, hits_sweep_bsr, pad_empty_rows,
-                  pad_messages, seg_aggregate)
-from .seg_matmul import seg_matmul
+from .ops import (DeviceBSR, bsr_converge, bsr_matvec, classify_exit,
+                  hits_sweep_bsr, pad_empty_rows)
 
 __all__ = [
     "bsr_scaled_matvec", "bsr_converge_cols", "resolve_interpret",
     "DeviceBSR", "bsr_converge", "bsr_matvec", "classify_exit",
-    "build_tiled_segments", "hits_sweep_bsr", "pad_empty_rows",
-    "pad_messages", "seg_aggregate", "seg_matmul",
+    "hits_sweep_bsr", "pad_empty_rows",
 ]

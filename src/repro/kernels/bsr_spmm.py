@@ -6,7 +6,7 @@ matrix (or its transpose) and cin is the paper's Ch/Ca diagonal. The +2N
 multiplies the paper accounts for (Table 2) are fused into the block
 matmul's VMEM prologue — they never cost an HBM round trip.
 
-TPU mapping (see DESIGN.md §3): the grid walks the *nonzero blocks* sorted
+TPU mapping: the grid walks the *nonzero blocks* sorted
 by block-row; a scalar-prefetched (brow, bcol) table drives data-dependent
 BlockSpec index maps (the canonical TPU block-sparse pattern). Consecutive
 grid steps that share a block-row revisit the same output tile in VMEM, so

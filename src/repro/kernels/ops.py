@@ -8,7 +8,6 @@ import numpy as np
 
 from ..graph.structure import BSR, Graph, to_bsr
 from .bsr_spmm import bsr_converge_cols, bsr_scaled_matvec, resolve_interpret
-from .seg_matmul import seg_matmul
 
 
 # ---------------------------------------------------------------- BSR path
@@ -229,52 +228,3 @@ def hits_sweep_bsr(g: Graph, ca=None, ch=None, bs: int = 128,
 
     return sweep, lt, l
 
-
-# ---------------------------------------------------------- seg_matmul path
-def build_tiled_segments(dst: np.ndarray, n_nodes: int, bs: int = 128,
-                         tile_e: int = 256):
-    """Sort edges by destination and pad each destination-block's edge run to
-    whole tiles. Returns (order, blkid (n_tiles,), off (E_pad,1), valid
-    (E_pad,1), n_blocks); gathered messages must be permuted by ``order`` and
-    zero-padded to E_pad rows (see ``pad_messages``)."""
-    order = np.argsort(dst // bs, kind="stable")
-    dst_sorted = dst[order]
-    blk = dst_sorted // bs
-    n_blocks = (n_nodes + bs - 1) // bs
-    counts = np.bincount(blk, minlength=n_blocks)
-    tiles_per_blk = np.maximum(1, -(-counts // tile_e))
-    n_tiles = int(tiles_per_blk.sum())
-    e_pad = n_tiles * tile_e
-    blkid = np.repeat(np.arange(n_blocks, dtype=np.int32), tiles_per_blk)
-    off = np.zeros((e_pad, 1), np.int32)
-    valid = np.zeros((e_pad, 1), np.int32)
-    perm = np.full(e_pad, -1, np.int64)  # padded slot -> original edge
-    write = 0
-    read = 0
-    for b in range(n_blocks):
-        c = int(counts[b])
-        slots = int(tiles_per_blk[b]) * tile_e
-        off[write:write + c, 0] = dst_sorted[read:read + c] - b * bs
-        valid[write:write + c, 0] = 1
-        perm[write:write + c] = order[read:read + c]
-        write += slots
-        read += c
-    return {"perm": perm, "blkid": blkid, "off": off, "valid": valid,
-            "n_blocks": n_blocks, "e_pad": e_pad}
-
-
-def pad_messages(msgs: jnp.ndarray, seg) -> jnp.ndarray:
-    """Arrange per-edge messages into the padded tile layout."""
-    perm = np.maximum(seg["perm"], 0)
-    out = jnp.take(msgs, jnp.asarray(perm), axis=0)
-    return out * jnp.asarray(seg["valid"], msgs.dtype)
-
-
-def seg_aggregate(msgs, seg, *, bs: int = 128, n_nodes: int,
-                  interpret: bool | None = None):
-    """Full segment-sum: messages (E, F) -> node aggregates (n_nodes, F)."""
-    m = pad_messages(msgs, seg)
-    y = seg_matmul(jnp.asarray(seg["blkid"]), m, jnp.asarray(seg["off"]),
-                   jnp.asarray(seg["valid"]), seg["n_blocks"], bs=bs,
-                   interpret=resolve_interpret(interpret))
-    return y[:n_nodes]

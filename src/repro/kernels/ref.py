@@ -21,13 +21,3 @@ def bsr_scaled_matvec_ref(blocks, idx, x, cin, n_pad: int):
     y = jax.lax.fori_loop(0, blocks.shape[0], body, y)
     return y.astype(x.dtype)
 
-
-def seg_matmul_ref(blkid, msgs, off, valid, n_blocks: int, bs: int):
-    """Segment-sum oracle: scatter-add each valid message to its global row."""
-    n_tiles = blkid.shape[0]
-    tile_e = msgs.shape[0] // n_tiles
-    blk_per_edge = jnp.repeat(blkid, tile_e)
-    rows = blk_per_edge * bs + off[:, 0]
-    m = msgs.astype(jnp.float32) * valid.astype(jnp.float32)
-    out = jax.ops.segment_sum(m, rows, num_segments=n_blocks * bs)
-    return out.astype(msgs.dtype)

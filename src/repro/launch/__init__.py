@@ -1,1 +1,2 @@
-"""Launchers: mesh builders, multi-pod dry-run, train/serve/rank drivers."""
+"""Entry points: the whole-graph ranking job (``rank``), the query service
+(``serve_rank``), the compile cache they share, and the mesh builder."""

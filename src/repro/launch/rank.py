@@ -1,10 +1,9 @@
 """Production ranking launcher — the paper's workload as a job.
 
-Runs accelerated-HITS (or QI-HITS/PageRank) over a (synthetic or saved)
+Runs accelerated HITS (or plain HITS) over a (synthetic or saved)
 web graph with the fault-tolerant engine: sharding, checkpoint/restart,
-straggler tolerance. On a real TPU slice the same sweep lowers through
-sparse.dist.make_dist_hits_sweep onto the production mesh (see dryrun.py);
-here it runs on host devices.
+straggler tolerance. The engine runs its shards one after another on one
+device (``core/engine.py``).
 
   PYTHONPATH=src python -m repro.launch.rank --dataset wikipedia --scale 0.5 \
       --algorithm accel --backbutton --ckpt /tmp/rank_ckpt

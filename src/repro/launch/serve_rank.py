@@ -90,8 +90,9 @@ def zipf_query_stream(rng, n_nodes: int, n_queries: int, roots_per_query: int,
 
 
 def build_parser() -> argparse.ArgumentParser:
-    """The launcher's flags; their defaults are the served configuration
-    (``configs/hits_webgraph.CONFIG``)."""
+    """The launcher's flags; the defaults of those that set the served
+    configuration are ``RankServiceConfig``'s, but ``--backend``'s."""
+    from ..serve.rank_service import RankServiceConfig as C
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="wikipedia",
                     help="paper dataset name or 'synthetic'")
@@ -102,52 +103,49 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--requests", type=int, default=200)
     ap.add_argument("--roots", type=int, default=5)
     ap.add_argument("--vocab", type=int, default=64)
-    ap.add_argument("--v", type=int, default=8, help="batch width (columns)")
-    ap.add_argument("--tol", type=float, default=1e-10)
+    ap.add_argument("--v", type=int, default=C.v_max,
+                    help="batch width (columns)")
+    ap.add_argument("--tol", type=float, default=C.tol)
     ap.add_argument("--topk", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
-    from ..configs.hits_webgraph import CONFIG
-    ap.add_argument("--backend", default=CONFIG.serve_backend,
+    # the launcher picks by the devices it finds; the library stays dense
+    ap.add_argument("--backend", default="auto",
                     choices=["dense", "sharded", "bsr", "auto"],
                     help="sweep backend (see repro.serve.backends)")
-    ap.add_argument("--shard-mode", default=CONFIG.serve_shard_mode,
+    ap.add_argument("--shard-mode", default=C.shard_mode,
                     choices=["replicated", "dual_blocked"],
                     help="sharded backend edge-shard strategy")
-    ap.add_argument("--shard-devices", type=int, default=None,
+    ap.add_argument("--shard-devices", type=int, default=C.shard_devices,
                     help="sharded backend device count (default: all)")
-    ap.add_argument("--plan-cache", type=int,
-                    default=CONFIG.serve_plan_cache,
+    ap.add_argument("--plan-cache", type=int, default=C.plan_cache_size,
                     help="SweepPlan LRU entries (structural layouts cached "
                          "per union-subgraph hash; 0 disables)")
     ap.add_argument("--bsr-host-loop", action="store_true",
-                    default=not CONFIG.serve_bsr_fused,
+                    default=not C.bsr_fused,
                     help="bsr: host-driven convergence loop instead of the "
                          "fused on-device lax.while_loop")
-    ap.add_argument("--pipeline-depth", type=int,
-                    default=CONFIG.serve_pipeline_depth,
+    ap.add_argument("--pipeline-depth", type=int, default=C.pipeline_depth,
                     help="staged-dispatch batches in flight (1: serial; "
                          ">=2: overlap host assemble/plan with the "
                          "previous batch's device sweep)")
-    ap.add_argument("--sweep-dtype", default=CONFIG.serve_sweep_dtype,
+    ap.add_argument("--sweep-dtype", default=C.sweep_dtype,
                     help="precision ladder: run bulk sweeps at this dtype "
                          "(bf16|fp32|f64), then f64-polish to tol with a "
                          "residual certificate ('': single-phase)")
-    ap.add_argument("--polish-tol", type=float,
-                    default=CONFIG.serve_polish_tol,
-                    help="precision ladder polish tolerance (0: the "
-                         "configured --tol)")
-    ap.add_argument("--lumping", default=CONFIG.serve_lumping,
+    ap.add_argument("--polish-tol", type=float, default=C.polish_tol,
+                    help="precision ladder polish tolerance (0 or unset: "
+                         "the configured --tol)")
+    ap.add_argument("--lumping", default=C.lumping,
                     choices=["off", "on", "auto"],
                     help="plan-time lumped sweep reduction: drop isolated "
                          "union rows + collapse duplicate-pattern classes "
                          "before planning/sweeping (auto: only above the "
                          "reduction-ratio gate)")
-    ap.add_argument("--rank-k", type=int, default=CONFIG.serve_rank_k,
+    ap.add_argument("--rank-k", type=int, default=C.rank_k,
                     help="rank-stability early exit: stop a column once its "
                          "top-k authority ordering holds stable (0: exact "
                          "residual stopping)")
-    ap.add_argument("--stable-sweeps", type=int,
-                    default=CONFIG.serve_stable_sweeps,
+    ap.add_argument("--stable-sweeps", type=int, default=C.stable_sweeps,
                     help="consecutive stable sweeps required to early-exit")
     ap.add_argument("--frontend", default="sync",
                     choices=["sync", "queued"],
@@ -155,11 +153,9 @@ def build_parser() -> argparse.ArgumentParser:
                          "micro-batching RankQueue fed one request at a time")
     ap.add_argument("--arrival-qps", type=float, default=0.0,
                     help="queued: Poisson arrival rate (0: back-to-back)")
-    ap.add_argument("--deadline-ms", type=float,
-                    default=CONFIG.serve_deadline_ms,
+    ap.add_argument("--deadline-ms", type=float, default=C.deadline_ms,
                     help="queued: max extra batching latency per request")
-    ap.add_argument("--queue-depth", type=int,
-                    default=CONFIG.serve_queue_depth or None,
+    ap.add_argument("--queue-depth", type=int, default=C.queue_depth,
                     help="queued: max distinct pending root sets")
     ap.add_argument("--sla-ms", type=float, default=0.0,
                     help="queued: per-request deadline for EDF batching and "
@@ -167,16 +163,15 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--low-pri-frac", type=float, default=0.0,
                     help="queued: fraction of requests submitted at the "
                          "best-effort class (sheddable under overload)")
-    ap.add_argument("--shed-priority", type=int,
-                    default=CONFIG.serve_shed_priority,
+    ap.add_argument("--shed-priority", type=int, default=C.shed_priority,
                     help="queued: lowest priority class still guaranteed is "
                          "shed_priority-1; classes >= this may shed")
-    ap.add_argument("--spill-dir", default=CONFIG.serve_spill_dir or None,
+    ap.add_argument("--spill-dir", default=C.spill_dir,
                     help="cache spill directory (restart-survivable cache)")
-    ap.add_argument("--spill-policy", default=CONFIG.serve_spill_policy,
+    ap.add_argument("--spill-policy", default=C.spill_policy,
                     choices=["all", "evict"])
     ap.add_argument("--spill-keep-generations", type=int,
-                    default=CONFIG.serve_spill_keep_generations,
+                    default=C.spill_keep_generations,
                     help="spill GC: newest step_* generations kept per "
                          "entry stream (compacted at init and on drain)")
     ap.add_argument("--delta-file", default=None,
@@ -185,9 +180,7 @@ def build_parser() -> argparse.ArgumentParser:
                          "pages: k}); the queued frontend rolls it in on "
                          "SIGHUP with admission open and prints the "
                          "acknowledged graph version")
-    ap.add_argument("--stats-port", type=int,
-                    default=(CONFIG.serve_stats_port
-                             if CONFIG.serve_stats_port >= 0 else None),
+    ap.add_argument("--stats-port", type=int, default=None,
                     help="serve GET /healthz and /stats.json on this "
                          "loopback port (0: ephemeral, printed at start; "
                          "omit to disable)")

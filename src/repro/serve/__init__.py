@@ -8,8 +8,6 @@ _EXPORTS = {
     "backends": ("BACKENDS", "BsrSweepBackend", "DenseSweepBackend",
                  "ShardedSweepBackend", "SweepBackend", "SweepBatch",
                  "make_backend", "select_backend", "shared_mesh"),
-    "kvquant": ("dequantize_kv", "init_quant_cache", "quant_decode_attention",
-                "quantize_kv", "update_quant_cache"),
     "pipeline": ("PipelineJob", "ServePipeline"),
     "plans": ("BsrPlan", "DensePlan", "PlanCache", "ShardedPlan", "SweepPlan",
               "structure_key"),

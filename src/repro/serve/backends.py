@@ -61,7 +61,7 @@ from ..core.reordering import blocking_permutation
 from ..graph.structure import Graph
 from ..kernels.bsr_spmm import resolve_interpret
 from ..kernels.ops import DeviceBSR, bsr_converge, bsr_matvec, bsr_revalue
-from ..sparse.dist import (build_edge_shards_cols,
+from ..sparse.dist import (build_edge_shards_cols, collective_bytes,
                            collective_bytes_per_sweep_cols,
                            device_put_edge_args_cols,
                            make_dist_hits_sweep_cols,
@@ -645,7 +645,6 @@ class ShardedSweepBackend(SweepBackend):
                            dtype=jnp.float64) -> float:
         """Compile ONE sweep at these shapes and measure per-device ring
         wire bytes from the optimized HLO (the bench/test ladder probe)."""
-        from ..launch.hlo_analysis import collective_bytes
         zeros = np.zeros((n_pad, v))
         plan = self.plan(SweepBatch(
             h0=zeros, src=src, dst=dst, w=w, ca=zeros, ch=zeros, mask=zeros,

@@ -17,7 +17,8 @@ single-device sweep).
 """
 from __future__ import annotations
 
-from typing import Optional
+import re
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -360,6 +361,119 @@ def make_dist_hits_sweep_cols(mesh, mode: str, n_pad: int, axes=("data",)):
     raise ValueError(f"unsupported mode {mode}")
 
 
+# ------------------------------------------------- collectives in the HLO
+# Per-device collective bytes from a compiled program's optimized HLO text:
+# the output bytes of every all-gather / all-reduce / reduce-scatter /
+# all-to-all / collective-permute, times the trip count of the while loops
+# around it, inferred from each loop's condition.
+
+_DTYPE_BYTES = {
+    "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1, "f8e4m3": 1,
+    "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
+    "s32": 4, "u32": 4, "f32": 4,
+    "s64": 8, "u64": 8, "f64": 8, "c64": 8, "c128": 16,
+}
+
+_COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+                "collective-permute", "ragged-all-to-all")
+
+_SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
+
+
+def _shape_bytes(type_str: str) -> int:
+    """bytes of 'bf16[16,32]' or tuple '(f32[2]{0}, f32[3]{0})'."""
+    total = 0
+    for m in _SHAPE_RE.finditer(type_str):
+        dt, dims = m.group(1), m.group(2)
+        if dt not in _DTYPE_BYTES:
+            continue
+        n = 1
+        if dims:
+            for d in dims.split(","):
+                n *= int(d)
+        total += n * _DTYPE_BYTES[dt]
+    return total
+
+
+def _split_computations(hlo: str) -> Dict[str, list]:
+    comps: Dict[str, list] = {}
+    cur = None
+    for line in hlo.splitlines():
+        m = (re.match(r"^(?:ENTRY\s+)?%?([\w\.\-]+)\s*(?:\([^)]*\))?\s*->.*"
+                      r"\{\s*$", line)
+             or re.match(r"^(?:ENTRY\s+)?%?([\w\.\-]+)\s*\{\s*$", line))
+        if m:
+            cur = m.group(1)
+            comps[cur] = []
+            continue
+        if line.startswith("}"):
+            cur = None
+            continue
+        if cur is not None:
+            comps[cur].append(line)
+    return comps
+
+
+_WHILE_RE = re.compile(
+    r"while\(.*?\),\s*(?:condition=%?([\w\.\-]+),\s*body=%?([\w\.\-]+)"
+    r"|body=%?([\w\.\-]+),\s*condition=%?([\w\.\-]+))")
+
+
+def _trip_count(cond_lines) -> int:
+    best = 1
+    for line in cond_lines:
+        for m in re.finditer(r"constant\((\d+)\)", line):
+            best = max(best, int(m.group(1)))
+    return best
+
+
+def collective_bytes(hlo: str) -> dict:
+    """Per-device collective bytes (output sizes, trip-count weighted)."""
+    comps = _split_computations(hlo)
+    # multiplier per computation from (possibly nested) while loops
+    mult: Dict[str, float] = {name: 1.0 for name in comps}
+    changed = True
+    iters = 0
+    while changed and iters < 10:
+        changed = False
+        iters += 1
+        for name, lines in comps.items():
+            for line in lines:
+                for wm in _WHILE_RE.finditer(line):
+                    cond = wm.group(1) or wm.group(4)
+                    body = wm.group(2) or wm.group(3)
+                    trip = _trip_count(comps.get(cond, []))
+                    for target in (body, cond):
+                        if target in mult:
+                            new = mult[name] * trip
+                            if new > mult[target]:
+                                mult[target] = new
+                                changed = True
+    per_kind: Dict[str, float] = {}
+    count = 0
+    for name, lines in comps.items():
+        m = mult.get(name, 1.0)
+        for line in lines:
+            lm = re.match(r"\s*(?:ROOT\s+)?%?[\w\.\-]+\s*=\s*(.+?)\s+"
+                          r"([a-z\-]+)(?:-start)?\(", line)
+            if not lm:
+                continue
+            op = lm.group(2)
+            if op.endswith("-done"):
+                continue
+            base = None
+            for c in _COLLECTIVES:
+                if op == c or op == c + "-start":
+                    base = c
+            if base is None:
+                continue
+            b = _shape_bytes(lm.group(1)) * m
+            per_kind[base] = per_kind.get(base, 0.0) + b
+            count += 1
+    return {"total_bytes": sum(per_kind.values()), "by_kind": per_kind,
+            "n_collective_ops": count}
+
+
 # ring-algorithm wire bytes per HLO collective OUTPUT byte: an all-reduce
 # is reduce-scatter + all-gather (~2(S-1)/S), one-phase collectives (S-1)/S
 _RING_WIRE_FACTOR = {"all-reduce": 2.0, "all-gather": 1.0,
@@ -368,7 +482,7 @@ _RING_WIRE_FACTOR = {"all-reduce": 2.0, "all-gather": 1.0,
 
 
 def wire_bytes_from_collectives(by_kind: dict, n_shards: int) -> float:
-    """Convert ``launch.hlo_analysis.collective_bytes``'s per-kind output
+    """Convert ``collective_bytes``'s per-kind output
     sizes into ring wire bytes — the metric the ladder above ranks by."""
     if n_shards <= 1:
         return 0.0
@@ -394,71 +508,6 @@ def collective_bytes_per_sweep_cols(mode: str, n_pad: int, v: int,
     if mode == "dual_blocked":
         return int(2 * payload * frac)
     raise ValueError(mode)
-
-
-def make_dryrun_rank_sweep(mesh, n: int, axes, mode: str = "baseline",
-                           n_hub: int | None = None):
-    """Sweep for the dry-run (and launch.rank): edge shards arrive as ARGS
-    (ShapeDtypeStructs at lower time), ca/ch folded into per-edge weights
-    host-side (w_e = ch[src_e] for the authority pass; the hub pass reuses
-    the same arrays with ca gathered at dst — see launch.rank).
-
-    Modes: baseline (replicated vector, 2 psums/sweep) | dual_blocked
-    (block-owned scatters, 2 all-gathers/sweep) | +bf16 (vector/weight
-    storage bf16, fp32 accumulation for norms/residuals).
-    """
-    ax = tuple(axes) if len(axes) > 1 else axes[0]
-    espec = P(ax, None)
-    n_shards = int(np.prod([mesh.shape[a] for a in axes]))
-
-    if "dual_blocked" in mode:
-        # "compact": hub vectors live in the reordered non-dangling space
-        # (paper's reordering insight applied to the distributed layout —
-        # dangling pages have zero hub score, so never ship them)
-        n_h = n_hub if ("compact" in mode and n_hub) else n
-        nb_a = -(-n // n_shards)
-        nb_h = -(-n_h // n_shards)
-
-        def sweep(h_blk, asrc, adst, aw, am, hsrc, hdst, hw, hm):
-            dt = h_blk.dtype
-            # gather in storage dtype; the barrier pins the convert AFTER
-            # the collective (XLA otherwise hoists bf16->f32 onto the wire)
-            h_full = jax.lax.all_gather(h_blk[0], ax, tiled=True)  # (n_h,)
-            h_full = jax.lax.optimization_barrier(h_full).astype(jnp.float32)
-            blk_id = _flat_axis_index(axes)
-            wmask = (aw[0] * am[0]).astype(jnp.float32)
-            hw_g = jnp.take(h_full, asrc[0], axis=0) * wmask  # compact src
-            a_blk = _seg_sum(hw_g, adst[0] - blk_id * nb_a, nb_a).astype(dt)
-            a_full = jax.lax.all_gather(a_blk, ax, tiled=True)     # (n,)
-            a_full = jax.lax.optimization_barrier(a_full).astype(jnp.float32)
-            wmask_h = (hw[0] * hm[0]).astype(jnp.float32)
-            aw_g = jnp.take(a_full, hsrc[0], axis=0) * wmask_h
-            h_new_blk = _seg_sum(aw_g, hdst[0] - blk_id * nb_h, nb_h)
-            tot = jax.lax.psum(jnp.sum(jnp.abs(h_new_blk)), ax)
-            h_new_blk = (h_new_blk / (tot + 1e-30)).astype(dt)
-            return h_new_blk[None], a_blk[None]
-
-        return jax.shard_map(sweep, mesh=mesh,
-                             in_specs=(espec,) + (espec,) * 8,
-                             out_specs=(espec, espec))
-
-    def sweep(h, src, dst, w, mask):
-        dt = h.dtype
-        wm = w[0] * mask[0]
-        a_p = _seg_sum(jnp.take(h, src[0], axis=0)
-                       * (wm[:, None] if h.ndim == 2 else wm), dst[0], n)
-        a = jax.lax.psum(a_p, ax)
-        h_p = _seg_sum(jnp.take(a, dst[0], axis=0)
-                       * (wm[:, None] if h.ndim == 2 else wm), src[0], n)
-        h_new = jax.lax.psum(h_p, ax)
-        tot = jnp.sum(jnp.abs(h_new.astype(jnp.float32)), axis=0,
-                      keepdims=h.ndim > 1)
-        h_new = (h_new.astype(jnp.float32) / (tot + 1e-30)).astype(dt)
-        return h_new, a
-
-    return jax.shard_map(sweep, mesh=mesh,
-                         in_specs=(P(), espec, espec, espec, espec),
-                         out_specs=(P(), P()))
 
 
 def blocked_to_full(h_blk: np.ndarray, n: int) -> np.ndarray:

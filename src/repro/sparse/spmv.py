@@ -2,12 +2,12 @@
 
 JAX has no CSR/CSC (BCOO only), so the portable sparse primitive is an
 edge-list scatter-add: ``segment_sum(x[gather] * w, scatter)``. All ranking
-algorithms and the GNN message passing are built on these two ops. The
+algorithms are built on these two ops. The
 Pallas BSR kernel (repro.kernels.bsr_spmm) is the TPU hot path for the same
 contraction; these functions are its semantic reference.
 
 Vectors may be (N,) or (N, V) — multi-vector iteration batches V ranking
-vectors through one traversal (MXU-friendly; see DESIGN.md §3).
+vectors through one traversal (MXU-friendly).
 """
 from __future__ import annotations
 

@@ -1,8 +1,8 @@
 import jax
 
-# fp64 for the ranking oracles (models pass explicit fp32/bf16 dtypes, so
-# they are unaffected). Do NOT set XLA_FLAGS here — smoke tests and benches
-# must see the real single-device CPU; dry-run spawns its own process.
+# fp64 for the ranking oracles. Do NOT set XLA_FLAGS here — tests and
+# benches must see the real single-device CPU; the multi-device tests spawn
+# their own processes.
 jax.config.update("jax_enable_x64", True)
 
 # Property-test modules import hypothesis at module level; on bare

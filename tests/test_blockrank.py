@@ -1,14 +1,8 @@
-"""BlockRank-style warm start (paper §2) + int8 KV cache."""
-import jax
-import jax.numpy as jnp
+"""BlockRank-style warm start (paper §2)."""
 import numpy as np
 
 from repro.core import accel_hits, qi_hits
 from repro.core.blockrank import block_warm_start, hits_blockrank, host_blocks
-from repro.graph import WebGraphSpec, generate_webgraph
-from repro.serve import (init_quant_cache, quant_decode_attention,
-                         quantize_kv, dequantize_kv, update_quant_cache)
-from repro.models.layers import decode_attention
 
 
 def _blocky_graph(seed=0):
@@ -53,26 +47,3 @@ def test_block_warm_start_is_distribution():
     assert np.isclose(h0.sum(), 1.0)
     assert (h0 >= 0).all()
 
-
-def test_kv_quant_roundtrip():
-    x = jax.random.normal(jax.random.key(0), (2, 8, 4, 16), jnp.float32)
-    q, s = quantize_kv(x)
-    xr = dequantize_kv(q, s)
-    scale = np.asarray(s)
-    assert float(jnp.abs(x - xr).max()) <= scale.max() * 1.01
-    assert q.dtype == jnp.int8
-
-
-def test_quant_decode_attention_close_to_fp():
-    key = jax.random.key(1)
-    b, s, hkv, h, dh = 2, 12, 2, 4, 16
-    k = jax.random.normal(key, (b, s, hkv, dh), jnp.float32)
-    v = jax.random.normal(jax.random.key(2), (b, s, hkv, dh), jnp.float32)
-    q = jax.random.normal(jax.random.key(3), (b, h, dh), jnp.float32)
-    cache = {k2: v2[0] for k2, v2 in init_quant_cache(1, b, s, hkv, dh).items()}
-    for pos in range(s):
-        cache = update_quant_cache(cache, k[:, pos], v[:, pos], pos)
-    out_q = quant_decode_attention(q, cache, length=s)
-    out_fp = decode_attention(q, k, v, length=s)
-    rel = float(jnp.abs(out_q - out_fp).max() / (jnp.abs(out_fp).max() + 1e-9))
-    assert rel < 0.05, rel

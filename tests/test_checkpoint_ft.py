@@ -1,6 +1,5 @@
-"""Checkpoint/restore, preemption resume, straggler tolerance, elasticity."""
+"""Checkpoint/restore, straggler tolerance, elasticity."""
 import os
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -10,23 +9,23 @@ from repro import checkpoint as ck
 from repro.core import accel_hits
 from repro.core.engine import RankingEngine
 from repro.graph import WebGraphSpec, generate_webgraph
-from repro.models import TransformerConfig, init_params, loss_fn
-from repro.train import AdamWConfig, DataConfig, init_opt_state, lm_batch, make_train_step
-
-CFG = TransformerConfig(name="t", n_layers=2, d_model=32, n_heads=2,
-                        n_kv_heads=1, d_head=16, d_ff=64, vocab=64,
-                        remat=False)
 
 
 def test_checkpoint_roundtrip(tmp_path):
-    params = init_params(CFG, jax.random.key(0))
-    opt = init_opt_state(params)
+    ks = jax.random.split(jax.random.key(0), 3)
+    params = {"h": jax.random.uniform(ks[0], (40,), jnp.float64),
+              "blocks": [jax.random.normal(ks[1], (4, 8), jnp.float32),
+                         jax.random.normal(ks[2], (8,), jnp.float32)]}
+    opt = {"step": jnp.asarray(3, jnp.int32),
+           "staleness": np.arange(8, dtype=np.int64)}
     ck.save(str(tmp_path), 7, {"params": params, "opt": opt},
             extra={"note": "x"})
     tree, step, extra = ck.restore(str(tmp_path),
                                    {"params": params, "opt": opt})
     assert step == 7 and extra["note"] == "x"
-    for a, b in zip(jax.tree.leaves(tree["params"]), jax.tree.leaves(params)):
+    for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(
+            {"params": params, "opt": opt})):
+        assert np.asarray(a).dtype == np.asarray(b).dtype
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
@@ -58,31 +57,6 @@ def test_junk_step_dirs_read_as_absent(tmp_path):
     assert (tmp_path / "step_12.orig").is_dir()
     tree, step, _ = ck.restore(str(tmp_path), params)
     assert step == 2
-
-
-def test_preemption_resume_bit_identical(tmp_path):
-    """Train 6 steps straight == train 3, checkpoint, restore, train 3."""
-    oc = AdamWConfig(lr=1e-3)
-    dc = DataConfig(kind="lm", global_batch=4, seq_len=8, vocab=64, seed=5)
-    step = jax.jit(make_train_step(partial(loss_fn, cfg=CFG), oc))
-
-    p = init_params(CFG, jax.random.key(0))
-    s = init_opt_state(p)
-    for i in range(6):
-        p, s, _ = step(p, s, lm_batch(dc, i))
-
-    p2 = init_params(CFG, jax.random.key(0))
-    s2 = init_opt_state(p2)
-    for i in range(3):
-        p2, s2, _ = step(p2, s2, lm_batch(dc, i))
-    ck.save(str(tmp_path), 3, {"params": p2, "opt": s2})
-    restored, start, _ = ck.restore(str(tmp_path), {"params": p2, "opt": s2})
-    p3, s3 = restored["params"], restored["opt"]
-    for i in range(start, 6):
-        p3, s3, _ = step(p3, s3, lm_batch(dc, i))
-    for a, b in zip(jax.tree.leaves(p), jax.tree.leaves(p3)):
-        np.testing.assert_allclose(np.asarray(a, np.float32),
-                                   np.asarray(b, np.float32), atol=1e-6)
 
 
 def test_engine_straggler_tolerance():

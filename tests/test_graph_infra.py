@@ -1,13 +1,10 @@
-"""Graph substrate: generators (hypothesis), partitions, sampler, BSR."""
-import jax
-import jax.numpy as jnp
+"""Graph substrate: generators (hypothesis), partitions, BSR."""
 import numpy as np
 from hypothesis import given, settings, strategies as st
 
-from repro.graph import (Graph, SamplerTables, WebGraphSpec,
-                         generate_webgraph, khop_sizes, paper_dataset,
+from repro.graph import (WebGraphSpec, generate_webgraph, paper_dataset,
                          partition_edges, partition_edges_by_dst_block,
-                         sample_khop, to_bsr, to_csr)
+                         to_bsr, to_csr)
 from repro.graph.generators import PAPER_TABLE7
 from repro.kernels.ops import pad_empty_rows
 
@@ -82,32 +79,3 @@ def test_bsr_dense_equivalence():
     present[padded.brow] = True
     assert present.all()
 
-
-def test_sampler_shapes_and_masks():
-    g = generate_webgraph(WebGraphSpec(300, 2400, 0.5, seed=5))
-    tabs = SamplerTables.build(g, max_deg=32)
-    seeds = jnp.arange(16)
-    sub = sample_khop(jax.random.key(0), tabs, seeds, (5, 3))
-    n_tot, e_tot = khop_sizes(16, (5, 3))
-    assert sub.nodes.shape == (n_tot,)
-    assert sub.edge_src.shape == (e_tot,)
-    # masked edges only from zero-degree frontier nodes
-    deg = np.asarray(g.outdeg())
-    nodes = np.asarray(sub.nodes)
-    src_nodes = nodes[np.asarray(sub.edge_src)]
-    em = np.asarray(sub.edge_mask)
-    dst_nodes = nodes[np.asarray(sub.edge_dst)]
-    assert (deg[dst_nodes[em]] > 0).all()
-    # sampled neighbors are true neighbors
-    edges = set(zip(g.src.tolist(), g.dst.tolist()))
-    for s, d, m in zip(src_nodes, dst_nodes, em):
-        if m:
-            assert (int(d), int(s)) in edges  # child sampled from parent's out-nbrs
-
-
-def test_sampler_deterministic():
-    g = generate_webgraph(WebGraphSpec(200, 1500, 0.4, seed=6))
-    tabs = SamplerTables.build(g, max_deg=16)
-    s1 = sample_khop(jax.random.key(42), tabs, jnp.arange(8), (4, 2))
-    s2 = sample_khop(jax.random.key(42), tabs, jnp.arange(8), (4, 2))
-    np.testing.assert_array_equal(np.asarray(s1.nodes), np.asarray(s2.nodes))

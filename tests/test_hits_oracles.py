@@ -1,9 +1,12 @@
 """Sparse JAX implementations vs dense fp64 numpy oracles (ground truth)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import accel_hits, back_button, pagerank, qi_hits
+from repro.core import (accel_hits, accel_weights, back_button, pagerank,
+                        qi_hits, spearman)
+from repro.core.hits import EdgeList, hits_sweep
 from repro.core.ref_dense import (accel_hits_dense, pagerank_dense,
                                   qi_hits_dense)
 from repro.graph import WebGraphSpec, generate_webgraph
@@ -70,3 +73,18 @@ def test_multivector_iteration_consistent():
     r4 = accel_hits(g, tol=1e-12, v=4)
     for j in range(4):
         np.testing.assert_allclose(r4.v[:, j], r1.v, atol=1e-10)
+
+
+def test_bf16_power_iteration_preserves_ranking():
+    """bf16-storage sweeps (the ranking +bf16 mode numerics) keep ordering."""
+    g = generate_webgraph(WebGraphSpec(400, 3000, 0.6, seed=21))
+    exact = accel_hits(g, tol=1e-11)
+    ca, ch = accel_weights(g.indeg(), g.outdeg())
+    sweep = jax.jit(hits_sweep(EdgeList.from_graph(g),
+                               ca=jnp.asarray(ca, jnp.float32),
+                               ch=jnp.asarray(ch, jnp.float32)))
+    h = jnp.full((g.n_nodes,), 1.0 / g.n_nodes, jnp.bfloat16)
+    for _ in range(60):
+        h, _ = sweep(h.astype(jnp.float32))
+        h = h.astype(jnp.bfloat16)  # storage dtype between sweeps
+    assert spearman(np.asarray(h, np.float64), exact.v) > 0.98

@@ -7,8 +7,8 @@ import pytest
 
 from repro.core import accel_weights
 from repro.graph import Graph, WebGraphSpec, generate_webgraph, to_bsr
-from repro.kernels import (DeviceBSR, bsr_matvec, build_tiled_segments,
-                           hits_sweep_bsr, pad_empty_rows, seg_aggregate)
+from repro.kernels import (DeviceBSR, bsr_matvec, hits_sweep_bsr,
+                           pad_empty_rows)
 from repro.kernels.ref import bsr_scaled_matvec_ref
 from repro.sparse.spmv import spmv_dst
 
@@ -69,20 +69,6 @@ def test_bsr_empty_rows_written():
     y = bsr_matvec(lt, x)
     y_ref = spmv_dst(x, jnp.asarray(g.src), jnp.asarray(g.dst), 100)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref))
-
-
-@pytest.mark.parametrize("bs,tile_e", [(32, 64), (128, 256), (64, 128)])
-@pytest.mark.parametrize("f", [4, 16])
-def test_seg_matmul_sweep(bs, tile_e, f):
-    g = _graph(400, 3000, seed=bs + f)
-    msgs = jax.random.normal(jax.random.key(f), (g.n_edges, f), jnp.float32)
-    seg = build_tiled_segments(np.asarray(g.dst), g.n_nodes, bs=bs,
-                               tile_e=tile_e)
-    agg = seg_aggregate(msgs, seg, bs=bs, n_nodes=g.n_nodes)
-    ref = jax.ops.segment_sum(msgs, jnp.asarray(g.dst),
-                              num_segments=g.n_nodes)
-    np.testing.assert_allclose(np.asarray(agg), np.asarray(ref), rtol=1e-5,
-                               atol=1e-5)
 
 
 # ------------------------------------------- multi-column RHS (serve path)

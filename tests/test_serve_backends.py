@@ -155,6 +155,9 @@ for mode in ("replicated", "dual_blocked"):
     measured[mode] = meas
     print(f"{mode}: measured_wire={meas} analytic={analytic}")
     assert meas > 0, mode
+    # the HLO holds the collectives the analytic model counts, each once
+    # (plus the L1 norm's few-byte psum)
+    assert abs(meas - analytic) <= 0.01 * analytic, (mode, meas, analytic)
 # the dist ladder, measured from compiled HLO: blocked moves fewer bytes
 assert measured["dual_blocked"] <= measured["replicated"], measured
 print("LADDER OK")

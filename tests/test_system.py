@@ -1,15 +1,12 @@
 """End-to-end behaviour tests: the paper's full pipeline (crawl-like graph ->
-accelerated ranking -> retrieval integration) and the Pallas-kernel path."""
-import jax
+accelerated ranking) and the Pallas-kernel path."""
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import (accel_hits, back_button, cosine, qi_hits,
                         topk_overlap)
 from repro.core.engine import RankingEngine
-from repro.graph import bipartite_interactions, paper_dataset
-from repro.models.recsys import (TwoTowerConfig, init_twotower_params,
-                                 retrieval_topk)
+from repro.graph import paper_dataset
 
 
 def test_end_to_end_ranking_pipeline():
@@ -37,26 +34,6 @@ def test_end_to_end_engine_with_kernel_path():
     for _ in range(r.iters + 5):
         h, _ = sweep(h)
     assert np.abs(np.asarray(h, np.float64) - r.hub).max() < 1e-4
-
-
-def test_retrieval_with_hits_prior():
-    """The paper's technique as a retrieval feature: authority prior over a
-    bipartite user->item graph reorders candidates toward popular items."""
-    n_users, n_items = 300, 500
-    g = bipartite_interactions(n_users, n_items, 4000, seed=3)
-    r = accel_hits(g, tol=1e-9)
-    prior = np.asarray(r.aux[n_users:]) + 1e-12     # item authority
-    cfg = TwoTowerConfig(name="tt", embed_dim=8, tower_mlp=(16, 8),
-                         n_users=n_users, n_items=n_items)
-    params = init_twotower_params(cfg, jax.random.key(0))
-    cands = jnp.arange(n_items)
-    _, base_idx = retrieval_topk(params, jnp.array([5]), cands, k=50)
-    _, prior_idx = retrieval_topk(params, jnp.array([5]), cands, k=50,
-                                  prior=jnp.asarray(prior), prior_weight=1.0)
-    base_rank = np.asarray(base_idx[0])
-    prior_rank = np.asarray(prior_idx[0])
-    # prior-blended top-k has higher average authority than the base top-k
-    assert prior[prior_rank].mean() > prior[base_rank].mean()
 
 
 def test_power_method_jit_matches_host_loop():

@@ -110,19 +110,6 @@ def test_bsr_fused_loop_compiles_under_x64(one_chip, ladder):
     _compiled_ok(compiled, kernel=True)
 
 
-def test_seg_matmul_compiles_under_x64(one_chip):
-    """The one-hot segment-sum kernel, the other Pallas kernel here."""
-    from repro.kernels.seg_matmul import seg_matmul
-    s = lambda shape, dt: _shape(one_chip, shape, dt)  # noqa: E731
-    n_tiles, tile_e, f = 64, 256, 8
-    e = n_tiles * tile_e
-    compiled = seg_matmul.lower(
-        s((n_tiles,), jnp.int32), s((e, f), jnp.float32),
-        s((e, 1), jnp.int32), s((e, 1), jnp.int32), 32, bs=BS,
-        interpret=False).compile()
-    _compiled_ok(compiled, kernel=True)
-
-
 # ------------------------------------------------------ whole-graph engine
 
 
